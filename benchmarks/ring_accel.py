@@ -22,6 +22,21 @@ from repro.neuro.sim import simulate
 
 
 def run() -> list[dict]:
+    import jax
+
+    from repro.kernels import ops
+
+    # off the TPU the kernel runs in interpret mode — this benchmark's
+    # explicit choice (the rows below are labelled pallas-interpret)
+    prev = ops.FORCE_INTERPRET
+    ops.FORCE_INTERPRET = jax.default_backend() != "tpu"
+    try:
+        return _run()
+    finally:
+        ops.FORCE_INTERPRET = prev
+
+
+def _run() -> list[dict]:
     rows = []
     overheads = {}
     for cells in (64, 128, 256):

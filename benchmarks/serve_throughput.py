@@ -178,7 +178,7 @@ def bench(arch: str = "deepseek-7b", *, smoke: bool = False,
     # on TPU the Pallas kernel's online-softmax accumulation is
     # tolerance-verified by the kernel-parity suite instead, so a
     # mismatch there is a warning and the ledger metric records ungated
-    parity_exact = not kops.use_paged_kernel()
+    parity_exact = kops.paged_attention_impl() == "reference"
     if not kernel_parity:
         findings.append({
             "severity": "error" if parity_exact else "warn",

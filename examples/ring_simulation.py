@@ -5,8 +5,10 @@ path (the dual-environment check on real physiology).
 
     PYTHONPATH=src python examples/ring_simulation.py
 """
+import jax
 import numpy as np
 
+from repro.kernels import ops
 from repro.neuro.cable import CellConfig
 from repro.neuro.ring import RingConfig
 from repro.neuro.sim import simulate
@@ -24,6 +26,8 @@ reached = front[front >= 0]  # -1 = no spike that epoch (EPSP rise time can
 assert (np.diff(reached) >= 0).all(), "wave must advance monotonically"
 assert r.total_spikes == int(reached[-1]) + 1
 
+# off the TPU the HH kernel runs in interpret mode, by explicit choice
+ops.FORCE_INTERPRET = jax.default_backend() != "tpu"
 rk = simulate(cfg, use_pallas=True)
 assert np.array_equal(np.asarray(r.spike_counts),
                       np.asarray(rk.spike_counts)), "kernel parity"

@@ -17,8 +17,8 @@ from repro.serve import PagedServeEngine, Request, SamplingParams
 
 if __name__ == "__main__":
     # the one-call driver (submit + drain under the hood)
-    res = serve("deepseek-7b", n_requests=8, slots=4, max_len=96, max_new=12,
-                shared_prefix=24)
+    res = serve("deepseek-7b", reduced=True, n_requests=8, slots=4,
+                max_len=96, max_new=12, shared_prefix=24)
     print(json.dumps(res, indent=1))
     assert res["served"] == 8
     assert res["engine"] == "paged" and res["cached_tokens"] > 0

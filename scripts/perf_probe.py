@@ -6,6 +6,8 @@ the three roofline terms + the top collectives with their HLO context.
 """
 import os
 
+# a CPU-only lowering tool: 512 placeholder devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
@@ -22,7 +24,10 @@ from repro.launch.mesh import make_production_mesh, mesh_config
 from repro.models import build
 from repro.parallel import bind as ctx_bind, rules_for
 
-PEAK, HBM, ICI = 197e12, 819e9, 50e9
+from repro.core.peaks import chip_peaks
+
+_V5E = chip_peaks("TPU v5 lite")      # the chip the cells are lowered for
+PEAK, HBM, ICI = _V5E.bf16_flops, _V5E.hbm_bw, _V5E.ici_link_bw
 
 
 def probe(arch, shape_name, rules="auto", remat="full", mb=None,

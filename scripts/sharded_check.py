@@ -2,6 +2,7 @@
 and numeric parity sharded-vs-single-device.  Run in a subprocess."""
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"   # 8 placeholder CPU devices
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import sys

@@ -25,7 +25,6 @@ import argparse
 import json
 import os
 import shutil
-import subprocess
 import sys
 from pathlib import Path
 
@@ -191,13 +190,17 @@ def smoke_arch(name: str) -> dict:
             "step_loss": float(m["loss"])}
 
 
-def run_bench(script: str, extra: list[str]) -> dict:
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", script),
-         "--smoke"] + extra,
-        capture_output=True, text=True, cwd=REPO)
-    assert out.returncode == 0, (script, out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+def run_bench(name: str, ledger_dir: str, update_baseline: bool) -> dict:
+    """One ``benchmarks/<name>.py --smoke`` run, in this process: the
+    harness has already touched JAX, and a child that needed the same
+    accelerator would fail or hang on it."""
+    import importlib
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    mod = importlib.import_module(f"benchmarks.{name}")
+    return mod.bench(smoke=True, ledger_dir=ledger_dir,
+                     update_baseline=update_baseline)
 
 
 def main() -> int:
@@ -225,22 +228,21 @@ def main() -> int:
 
     # serving + audit smokes: findings fold into the one diagnostics gate
     diag = Diagnostics()
-    ledger_flags = ["--ledger-dir", args.ledger_dir] + (
-        ["--update-baseline"] if args.update_baseline else [])
+    ledger_flags = (args.ledger_dir, args.update_baseline)
 
-    serve_rec = run_bench("serve_throughput.py", ledger_flags)
+    serve_rec = run_bench("serve_throughput", *ledger_flags)
     diag.extend(serve_rec["findings"], source="serve_throughput")
 
-    audit_rec = run_bench("audit_pathways.py", ledger_flags)
+    audit_rec = run_bench("audit_pathways", *ledger_flags)
     diag.extend(audit_rec["findings"], source="audit_pathways")
 
-    workloads_rec = run_bench("serve_workloads.py", ledger_flags)
+    workloads_rec = run_bench("serve_workloads", *ledger_flags)
     diag.extend(workloads_rec["findings"], source="serve_workloads")
 
-    cluster_rec = run_bench("serve_cluster.py", ledger_flags)
+    cluster_rec = run_bench("serve_cluster", *ledger_flags)
     diag.extend(cluster_rec["findings"], source="serve_cluster")
 
-    tiering_rec = run_bench("serve_tiering.py", ledger_flags)
+    tiering_rec = run_bench("serve_tiering", *ledger_flags)
     diag.extend(tiering_rec["findings"], source="serve_tiering")
 
     timeline_rec = timeline_smoke(args.ledger_dir, args.update_baseline)

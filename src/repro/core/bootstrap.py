@@ -9,6 +9,8 @@ exact PMIx triple — and everything else (device discovery, mesh
 construction, GSPMD partitioning) happens inside the "image".
 
 This module provides:
+  * setup_compile_cache() — the persistent XLA compilation cache every
+    entry point turns on first;
   * WireUp — the endpoint-resolution dataclass + env/Slurm detection
     (``--mpi=pmix`` analogue: SLURM_* variables → wire-up triple);
   * init_distributed() — binds it (no-op single-process, real
@@ -22,6 +24,28 @@ from __future__ import annotations
 import os
 import time
 from dataclasses import dataclass
+from pathlib import Path
+
+#: The compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed directory in the checkout (gitignored).  The path is part of the
+#: cache key, so it must not move between runs — never a temp name.
+CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives in the checkout's
+    ``.jax_cache``.  Call before the first compile (every entry point:
+    ``chip_smoke.py``, ``launch.serve``, ``launch.train``)."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE))
+    return str(CHECKOUT_CACHE)
 
 
 @dataclass(frozen=True)

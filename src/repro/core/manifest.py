@@ -85,9 +85,13 @@ class HostBinding:
     mesh_axes: tuple[str, ...]
     platform_: str = ""
     hostname: str = ""
-    peak_flops: float = 197e12       # bf16 / chip  (TPU v5e)
-    hbm_bw: float = 819e9            # B/s / chip
-    ici_bw: float = 50e9             # B/s / link
+
+    @property
+    def peaks(self):
+        """This chip's published peaks (``core.peaks``; raises for a
+        device kind without a table entry — never a borrowed default)."""
+        from repro.core.peaks import chip_peaks
+        return chip_peaks(self.device_kind)
 
     @classmethod
     def capture(cls, mesh) -> "HostBinding":
@@ -140,7 +144,8 @@ class Manifest:
         portable = PortableEnv(**raw["portable"])
         m = cls(portable=portable)
         if raw.get("binding"):
-            b = raw["binding"]
+            b = {k: v for k, v in raw["binding"].items()
+                 if k in {f.name for f in dataclasses.fields(HostBinding)}}
             b["mesh_shape"] = tuple(b["mesh_shape"])
             b["mesh_axes"] = tuple(b["mesh_axes"])
             m.binding = HostBinding(**b)

@@ -134,6 +134,70 @@ class DualEnvHarness:
         return report
 
 
+#: Relative L2 limit per logits row between two bf16 pathways of the
+#: reduced (4-layer) CPU models, or between one of them and the float32
+#: reference.  bf16's unit roundoff is 2^-9; at 4 layers each bf16 path
+#: sits 1.0-1.5e-2 from the float32 forward and the contiguous and paged
+#: engines 0.95e-2 from each other (CPU runs, seeds 0-3), so 3e-2 is a 2x
+#: margin.  Rounding the weights to fp8 (e4m3) measures 0.13-0.20.
+BF16_LOGITS_TOL = 3e-2
+
+
+def rel_l2(got, want) -> float:
+    """Largest per-row relative L2 error ``||got - want|| / ||want||`` over
+    the last axis of two same-shape arrays (rows of logits), in float64.
+    One number per row, so a few large logits cannot hide behind many
+    small ones, and the scale of the logits does not enter."""
+    a = np.asarray(got, np.float64).reshape(-1, np.shape(got)[-1])
+    b = np.asarray(want, np.float64).reshape(-1, np.shape(want)[-1])
+    return float(np.max(np.linalg.norm(a - b, axis=-1)
+                        / np.maximum(np.linalg.norm(b, axis=-1), 1e-30)))
+
+
+def logits_verdict(a: dict, b: dict, *, tol: float,
+                   greedy: bool = True) -> Verdict:
+    """Served logits of two runs of one workload, request by request.
+
+    ``a``/``b`` map a request id to ``(tokens, logits rows)``, one row per
+    output token (the row that token was chosen from).  Row ``t`` of both
+    runs comes from the same inputs only while the two streams agree on
+    every earlier token, so rows are compared (``rel_l2`` <= ``tol``) up
+    to and including the first token where the streams part, and not
+    after.  Streams may part only at a rounding tie: the parting rows
+    agree within ``tol`` and, for greedy decoding, each run's token is
+    its own row's argmax — two near-equal logits swapped order under a
+    difference the rows show.  (For sampled decoding both runs add the
+    same counter-keyed noise, so a parting row that agrees within ``tol``
+    is accepted as a tie of the perturbed scores.)"""
+    worst, rows, parted, bad = 0.0, 0, 0, []
+    for rid in sorted(set(a) | set(b)):
+        if rid not in a or rid not in b:
+            bad.append(f"rid {rid} missing")
+            continue
+        (ta, la), (tb, lb) = a[rid], b[rid]
+        n = min(len(ta), len(tb))
+        split = next((t for t in range(n) if ta[t] != tb[t]), None)
+        last = n if split is None else split + 1
+        for t in range(last):
+            worst = max(worst, rel_l2(la[t], lb[t]))
+            rows += 1
+        if split is not None:
+            parted += 1
+            if greedy and (ta[split] != int(np.argmax(la[split]))
+                           or tb[split] != int(np.argmax(lb[split]))):
+                bad.append(f"rid {rid} parted at token {split} on a "
+                           f"token that is not its row's argmax")
+        elif len(ta) != len(tb):
+            bad.append(f"rid {rid}: {len(ta)} vs {len(tb)} tokens")
+    ok = rows > 0 and worst <= tol and not bad
+    detail = (f"max rel L2 {worst:.3e} vs {tol:.1e} over {rows} rows; "
+              f"{parted} stream(s) parted at a tie")
+    if bad:
+        detail += "; " + "; ".join(bad[:4])
+    return Verdict(kind="logits", ok=bool(ok), detail=detail,
+                   measured=worst, bound=tol)
+
+
 def constant_vs_scaling_overhead(overheads: dict[int, float],
                                  tol: float = 0.5) -> str:
     """Classify an overhead curve the way the paper does for GPU-Arbor

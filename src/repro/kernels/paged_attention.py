@@ -9,6 +9,13 @@ layer exists to flag.  This kernel consumes the paged layout directly:
   * grid ``(slots, kv_heads, pages)`` with the page dimension sequential,
     so the flash running max / denominator / accumulator live in VMEM
     scratch across a slot's pages;
+  * the pool is head-major, ``[num_blocks, kv_heads, block_size, hd]``,
+    so one grid step's K/V block ``(1, 1, block_size, hd)`` has the
+    page's rows and the head dim as its last two (tiled) axes.  The
+    token-major layout ``[num_blocks, block_size, kv_heads, hd]`` needs a
+    block of 1 on the second-minor kv-head axis, which Mosaic refuses
+    (a second-minor block dim must be a multiple of 8 or the whole axis);
+    use a ``block_size`` that is a multiple of 8 (16 fills a bf16 tile);
   * the page table rides scalar prefetch
     (``pltpu.PrefetchScalarGridSpec``): the K/V block index maps read
     ``page_table[slot, page]`` to fetch the *physical* page, which is
@@ -27,7 +34,7 @@ layer exists to flag.  This kernel consumes the paged layout directly:
 
 ``paged_attention_ref`` is the pure-JAX oracle — the same math via a
 dense gather *through the page table* — used by the parity tests and as
-the dispatch fallback when the kernel cannot run (TP-sharded decode).
+the paged step's attention off the TPU (``kernels.ops``).
 """
 from __future__ import annotations
 
@@ -41,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
+                  o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
                   chunk: int, group: int, n_pages: int):
     b = pl.program_id(0)
@@ -65,8 +73,8 @@ def _paged_kernel(pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref, o_ref,
         cg = chunk * group
         hd = q_ref.shape[-1]
         q = q_ref[0, :, 0].reshape(cg, hd).astype(jnp.float32)
-        k = k_ref[0, :, 0].astype(jnp.float32)      # [bs, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)      # [bs, hd]
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [cg, bs]
@@ -100,15 +108,21 @@ def _paged_kernel(pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
-                           scale: float | None = None,
+                           layer=None, scale: float | None = None,
                            interpret: bool = False):
     """Decode/chunk attention over the paged KV pool.
 
     q          [B, C, KV, G, hd] — post-RoPE queries (C chunk positions)
-    k/v_pool   [num_blocks, block_size, KV, hd] — the shared page pool,
-               already holding this call's fresh rows (writes go through
-               the page table *before* attention, mirroring the dense
-               path's update-then-attend order)
+    k/v_pool   [layers, num_blocks, KV, block_size, hd] — the shared page
+               pool of every layer, already holding this call's fresh rows
+               (writes go through the page table *before* attention,
+               mirroring the dense path's update-then-attend order); a
+               4-D ``[num_blocks, KV, block_size, hd]`` pool is one layer
+    layer      int32 scalar (traced) — the layer to attend.  The kernel
+               selects it in its K/V index maps, so the layer scan hands
+               it the whole pool it carries: slicing one layer out first
+               would make XLA materialize that slice for the custom call
+               (the compile rehearsal measured a whole-pool temporary)
     page_table [B, n_pages] int32 — per-slot physical page indices; rows
                past a slot's allocation must hold a valid index (0) —
                they are masked, never out-of-bounds
@@ -118,28 +132,34 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
     Returns [B, C, KV, G, hd].  Rows ``>= n_new`` per lane are garbage
     the caller discards (same contract as ``chunk_decode_attention``).
     """
+    if k_pool.ndim == 4:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     b, c, kv, g, hd = q.shape
-    nb, bs, kv_p, hd_p = k_pool.shape
+    _, nb, kv_p, bs, hd_p = k_pool.shape
     assert (kv_p, hd_p) == (kv, hd), (k_pool.shape, q.shape)
     assert v_pool.shape == k_pool.shape
     n_pages = page_table.shape[1]
     assert page_table.shape == (b, n_pages)
     scale = scale if scale is not None else hd ** -0.5
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, kv, n_pages),
         in_specs=[
             pl.BlockSpec((1, c, 1, g, hd),
-                         lambda b, h, j, pt, pos, nn: (b, 0, h, 0, 0)),
+                         lambda b, h, j, li, pt, pos, nn: (b, 0, h, 0, 0)),
             # the paged read: physical page via the prefetched table
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, pt, pos, nn: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, pt, pos, nn: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, 1, bs, hd),
+                         lambda b, h, j, li, pt, pos, nn:
+                         (li[0], pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bs, hd),
+                         lambda b, h, j, li, pt, pos, nn:
+                         (li[0], pt[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, c, 1, g, hd),
-                               lambda b, h, j, pt, pos, nn: (b, 0, h, 0, 0)),
+                               lambda b, h, j, li, pt, pos, nn:
+                               (b, 0, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((c * g,), jnp.float32),      # running max
             pltpu.VMEM((c * g,), jnp.float32),      # running denominator
@@ -152,21 +172,26 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(page_table, pos, n_new, q, k_pool, v_pool)
+    )(layer, page_table, pos, n_new, q, k_pool, v_pool)
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new, *,
-                        scale: float | None = None):
+                        layer=None, scale: float | None = None):
     """Pure-JAX oracle: dense gather *through the page table* + masked
     softmax.  Bitwise-independent of the kernel (full softmax instead of
-    the online accumulation) but mathematically identical on valid rows."""
+    the online accumulation) but mathematically identical on valid rows.
+    Pools and ``layer`` as in :func:`paged_attention_pallas`."""
+    if k_pool.ndim == 5:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     b, c, kv, g, hd = q.shape
-    nb, bs, _, _ = k_pool.shape
+    nb, _, bs, _ = k_pool.shape
     n_pages = page_table.shape[1]
     scale = scale if scale is not None else hd ** -0.5
 
-    k = k_pool[page_table].reshape(b, n_pages * bs, kv, hd)
-    v = v_pool[page_table].reshape(b, n_pages * bs, kv, hd)
+    def rows(pool):   # [B, n_pages, KV, bs, hd] -> [B, n_pages*bs, KV, hd]
+        return pool[page_table].swapaxes(2, 3).reshape(b, n_pages * bs, kv, hd)
+
+    k, v = rows(k_pool), rows(v_pool)
     qf = q.astype(jnp.float32)
     s = jnp.einsum("bckgh,bskh->bkgcs", qf, k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale

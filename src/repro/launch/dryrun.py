@@ -1,8 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
+# The lines above MUST run before any jax import: jax locks the device
 # count at first backend init.  This module is the only place the 512
-# placeholder devices exist — tests and benches see the real single device.
+# placeholder devices exist — tests and benches see the real single device
+# — and it lowers on the CPU platform even where a TPU is attached.
 
 import argparse
 import json

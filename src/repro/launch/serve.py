@@ -1,7 +1,12 @@
-"""Serving driver: paged-KV engine over a smoke-scale model.
+"""Serving driver: the paged-KV engine over one architecture.
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b \
         --requests 8 --slots 4 [--temperature 0.8 --top-k 20 --top-p 0.95]
+
+The model is built at its published widths (``resolve_arch``).
+``--reduced`` (``serve(..., reduced=True)``) swaps in the tiny same-family
+``configs.base.reduced`` config instead: the CPU callers' choice (tests,
+``scripts/smoke_all.py``, quick runs without an accelerator).
 
 The paged path (prefix cache + chunked prefill + scheduler) is the
 default for attention-cache families; ``--engine contiguous`` selects the
@@ -41,7 +46,9 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from dataclasses import dataclass
 from pathlib import Path
+from typing import Any
 
 import jax
 import numpy as np
@@ -49,27 +56,54 @@ import numpy as np
 from repro.audit import (AuditContext, Evidence, EventLog, MetricsServer,
                          RunAudit, ServeMetrics, Tracer, attribution,
                          build_timelines, chrome_trace_bytes)
-from repro.configs.base import reduced
+from repro.configs.base import reduced as reduce_config
+from repro.core.bootstrap import setup_compile_cache
 from repro.core.registry import resolve_arch
 from repro.models import build
 from repro.serve import (ClusterEngine, PagedServeEngine, Request,
                          SamplingParams, ServeEngine)
 
 
-def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
-          max_len: int = 96, max_new: int = 16, seed: int = 0,
-          engine: str = "paged", block_size: int = 8,
-          chunk: int = 4, shared_prefix: int = 0,
-          use_prefix_cache: bool = True, kernel: str = "paged",
-          swap: bool = True, replicas: int = 1, routing: str = "affinity",
-          audit: bool = True, metrics_port: int | None = None,
-          metrics_linger: float = 0.0, trace_out: str | None = None,
-          temperature: float = 0.0, top_k: int = 0, top_p: float = 1.0,
-          sampling_seed: int = 0) -> dict:
+@dataclass
+class ServeRun:
+    """One ``run_serve`` call: the report ``serve`` returns, plus what a
+    caller that checks the run needs — the model and weights it served,
+    the engine after the drain, and the finished requests."""
+    out: dict
+    model: Any
+    params: Any
+    engine: Any
+    done: list
+
+
+def serve(arch: str, **kwargs) -> dict:
+    """Serve one seeded workload and return the JSON-able report (see
+    :func:`run_serve` for the arguments)."""
+    return run_serve(arch, **kwargs).out
+
+
+def run_serve(arch: str, *, reduced: bool = False, n_requests: int = 8,
+              slots: int = 4, max_len: int = 96, max_new: int = 16,
+              seed: int = 0, engine: str = "paged", block_size: int = 16,
+              chunk: int = 4, num_blocks: int | None = None,
+              shared_prefix: int = 0, keep_logits: bool = False,
+              use_prefix_cache: bool = True, kernel: str = "paged",
+              swap: bool = True, replicas: int = 1,
+              routing: str = "affinity", audit: bool = True,
+              metrics_port: int | None = None, metrics_linger: float = 0.0,
+              trace_out: str | None = None, temperature: float = 0.0,
+              top_k: int = 0, top_p: float = 1.0,
+              sampling_seed: int = 0) -> ServeRun:
+    """Build ``arch`` (published widths, or ``reduced``) with weights from
+    ``seed``, serve ``n_requests`` seeded prompts (a ``shared_prefix``
+    common to all, then 4-16 own tokens) and drain.  ``keep_logits``
+    records each output token's logits row on the requests."""
     if trace_out is not None and not audit:
         raise ValueError("--trace-out reconstructs timelines from the "
                          "audit tracer; drop --no-audit")
-    cfg = reduced(resolve_arch(arch))
+    cfg = resolve_arch(arch)
+    if reduced:
+        cfg = reduce_config(cfg)
     model = build(cfg)
     params = model.init_params(jax.random.PRNGKey(seed))
     sampling = SamplingParams(temperature=temperature, top_k=top_k,
@@ -119,12 +153,14 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
         eng = ClusterEngine(model, params, replicas=replicas, slots=slots,
                             max_len=max_len, block_size=block_size,
                             chunk=chunk, routing=routing,
+                            num_blocks=num_blocks,
                             use_prefix_cache=use_prefix_cache,
                             kernel=kernel, tracer=tracer,
                             replica_tracers=replica_tracers)
     elif engine == "paged":
         eng = PagedServeEngine(model, params, slots=slots, max_len=max_len,
                                block_size=block_size, chunk=chunk,
+                               num_blocks=num_blocks,
                                use_prefix_cache=use_prefix_cache,
                                kernel=kernel, swap=swap, tracer=tracer)
     else:
@@ -137,7 +173,7 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
         Request(rid=i,
                 prompt=prefix + rng.integers(
                     0, cfg.vocab_size, size=rng.integers(4, 17)).tolist(),
-                max_new=max_new, sampling=sampling)
+                max_new=max_new, sampling=sampling, keep_logits=keep_logits)
         for i in range(n_requests)
     ]
     t0 = time.time()
@@ -148,8 +184,11 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
 
     ttfts = [r.t_first - r.t_submit for r in done if r.t_first]
     rep = eng.report()
+    dev = jax.devices()[0]
     out = {
         "arch": cfg.name,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "engine": rep["engine"],
         "sampling": sampling.describe(),
         "served": rep["served"],
@@ -164,12 +203,13 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
         out.update({k: rep[k] for k in
                     ("replicas", "routing", "routed", "routed_affinity",
                      "routed_spills", "shared_hit_rate", "prefix_hit_rate",
-                     "preemptions", "kernel", "summary_rebuilds")})
+                     "preemptions", "kernel", "attention",
+                     "summary_rebuilds")})
     elif engine == "paged":
         out.update({k: rep[k] for k in
                     ("prefill_tokens", "cached_tokens", "prefix_hit_rate",
-                     "page_peak_utilization", "preemptions", "kernel",
-                     "swap", "swap_restore_rate",
+                     "pages", "page_peak_utilization", "preemptions",
+                     "kernel", "attention", "swap", "swap_restore_rate",
                      "restored_tokens", "recompute_tokens")})
     if run_audit is not None:
         lat = Evidence(tracer=run_audit.tracer).request_latencies()
@@ -212,19 +252,23 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
         if metrics_linger > 0:
             time.sleep(metrics_linger)
         server.close()
-    return out
+    return ServeRun(out=out, model=model, params=params, engine=eng,
+                    done=done)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config (configs.base."
+                         "reduced) instead of the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=96)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--engine", choices=["paged", "contiguous"],
                     default="paged")
-    ap.add_argument("--block-size", type=int, default=8)
+    ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--chunk", type=int, default=4)
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="length of a prompt prefix shared by all requests")
@@ -276,7 +320,8 @@ def main() -> None:
                          "Chrome-trace-event JSON (open in Perfetto or "
                          "chrome://tracing); needs the audit tracer")
     args = ap.parse_args()
-    res = serve(args.arch, n_requests=args.requests,
+    setup_compile_cache()
+    res = serve(args.arch, reduced=args.reduced, n_requests=args.requests,
                 slots=args.slots, max_len=args.max_len,
                 max_new=args.max_new, engine=args.engine,
                 block_size=args.block_size, chunk=args.chunk,

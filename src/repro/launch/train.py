@@ -21,7 +21,7 @@ from repro.audit import AuditContext, RunAudit
 from repro.configs import SHAPES, TINY_MESH
 from repro.configs.base import RunConfig, ShapeConfig, TrainConfig, reduced
 from repro.core import Diagnostics, Manifest, PortableEnv, parse_hlo
-from repro.core.bootstrap import WireUp, init_distributed
+from repro.core.bootstrap import WireUp, init_distributed, setup_compile_cache
 from repro.core.registry import resolve_arch
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.launch import bind as B
@@ -154,6 +154,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
+    setup_compile_cache()
     res = train(args.arch, smoke=args.smoke, steps=args.steps,
                 seq_len=args.seq_len, global_batch=args.global_batch,
                 ckpt_every=args.ckpt_every, out_dir=args.out,

@@ -235,8 +235,10 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     # fp32 softmax weights, one rounding after the PV product: the same
     # accumulation discipline as the Pallas paged kernel, so every decode
-    # pathway (single-token, chunked, paged) rounds at the same points
-    # and token streams stay bit-comparable across engines
+    # pathway (single-token, chunked, paged) rounds at the same points.
+    # Their matmuls still accumulate in different orders, so served
+    # logits agree to the bf16 band (core.verify.BF16_LOGITS_TOL), and a
+    # greedy near-tie may part two pathways' streams
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgs,bskh->bkgh", probs,
                      v_cache.astype(jnp.float32)).astype(x.dtype)
@@ -304,32 +306,39 @@ def chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
 
 def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
                                  k_pool: jax.Array, v_pool: jax.Array,
-                                 page_table: jax.Array, pos: jax.Array,
-                                 n_new: jax.Array):
+                                 layer: jax.Array, page_table: jax.Array,
+                                 pos: jax.Array, n_new: jax.Array, *,
+                                 attention: str):
     """Chunked decode directly over the paged KV pool — no dense per-slot
     working cache, no gather.
 
-    x [B,C,D]; k/v_pool [num_blocks, block_size, KV, hd] (one layer of
-    the shared device page pool); page_table [B, n_pages] int32 maps each
-    lane's logical block index to its physical page; pos/n_new as in
-    :func:`chunk_decode_attention`.
+    x [B,C,D]; k/v_pool [layers, num_blocks, KV, block_size, hd] (the
+    whole shared device page pool, head-major — see
+    ``kernels.paged_attention``) of which ``layer`` (traced int32) is
+    written and attended: the pool is never sliced per layer, so the
+    layer scan updates the carried pool in place; page_table [B, n_pages]
+    int32 maps each lane's logical block index to its physical page;
+    pos/n_new as in :func:`chunk_decode_attention`.
 
     Fresh K/V rows are scattered into the pool *through the page table*
     (each lane writes only its own private pages — shared, refcounted
     prefix pages are never a write target because writes start at
     ``pos >= matched_len`` and prefix matches are whole blocks), then
-    attention reads every page via the Pallas kernel
-    (``kernels.ops.paged_attention``; interpret mode off-accelerator).
-    Under tensor parallelism the pure-JAX page-table reference lowers
-    instead — still the paged pathway, just GSPMD-traceable.
+    attention reads every page by ``attention``: ``"pallas"`` (the
+    kernel) or ``"reference"`` (the pure-JAX page-table reference), as
+    chosen by ``kernels.ops.paged_attention_impl``.  The kernel runs per
+    device; a tensor-parallel trace must ask for the reference.
 
     Returns (y [B,C,D], new_k_pool, new_v_pool).
     """
     geom = head_geom(cfg, tp_size())
     hd, kv, g = geom.head_dim, geom.n_kv, geom.group
     b, c, _ = x.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    nb, bs = k_pool.shape[1], k_pool.shape[3]
     n_pages = page_table.shape[1]
+    if attention == "pallas" and tp_size() > 1:
+        raise ValueError("the Pallas paged kernel runs per device; under "
+                         "tensor parallelism use attention='reference'")
 
     q = (x @ p["wq"]).reshape(b, c, kv, g, hd)
     k_new = (x @ p["wk"]).reshape(b, c, kv, hd)
@@ -344,28 +353,27 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
         k_new = rope(k_new, idx, cfg.rope_theta)
 
     # masked scatter through the page table: row idx lands in physical
-    # page ``table[idx // bs]`` at offset ``idx % bs``.  Lanes write only
-    # their first n_new rows; anything out of range (idle slots, padding
-    # rows, idx beyond the table) resolves to page ``nb`` and drops.
+    # page ``table[idx // bs]`` at offset ``idx % bs`` (all heads).  Lanes
+    # write only their first n_new rows; anything out of range (idle
+    # slots, padding rows, idx beyond the table) resolves to page ``nb``
+    # and drops.
     ok = (jnp.arange(c)[None, :] < n_new[:, None]) & (idx < n_pages * bs)
     blk = jnp.clip(idx // bs, 0, n_pages - 1)
     page = jnp.take_along_axis(page_table, blk, axis=1)    # [B,C]
     page = jnp.where(ok, page, nb)
     off = idx % bs
-    k_pool = k_pool.at[page, off].set(k_new, mode="drop")
-    v_pool = v_pool.at[page, off].set(v_new, mode="drop")
+    # one scatter element per (row, head): the update window is a single
+    # hd-vector, minor in the pool's own layout, so XLA keeps the pool's
+    # default layout (a [kv, hd] window made it re-lay the whole carried
+    # pool out around the kernel call, twice per layer)
+    heads = jnp.arange(kv)[None, None, :]
+    page, off = page[:, :, None], off[:, :, None]
+    k_pool = k_pool.at[layer, page, heads, off].set(k_new, mode="drop")
+    v_pool = v_pool.at[layer, page, heads, off].set(v_new, mode="drop")
 
     from repro.kernels import ops as kops
-    if kops.use_paged_kernel() and tp_size() == 1:
-        out = kops.paged_attention(q, k_pool, v_pool, page_table, pos, n_new)
-    else:
-        # pure-JAX page-table reference: the same paged pathway (no dense
-        # working cache anywhere) with the dense path's exact rounding
-        # points, so CPU serving stays bit-comparable to the contiguous
-        # oracle; the Pallas kernel's online-softmax accumulation is
-        # held to the ref by the kernel-parity suite instead
-        from repro.kernels.paged_attention import paged_attention_ref
-        out = paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new)
+    out = kops.paged_attention(q, k_pool, v_pool, page_table, pos, n_new,
+                               layer=layer, impl=attention)
     out = out.reshape(b, c, kv * g * hd)
     out = constrain(out, ("act_batch", None, "act_heads"))
     y = out @ p["wo"]

@@ -180,19 +180,36 @@ def _prefill_attn(cfg, p, x, pos0=0):
     return y, k, v
 
 
+def _cast(tree: Any, dtype) -> Any:
+    return tree if dtype is None else jax.tree.map(
+        lambda a: a.astype(dtype), tree)
+
+
 def prefill(cfg: ModelConfig, params: dict, batch: dict,
-            cache_len: int | None = None):
+            cache_len: int | None = None, *, dtype=None,
+            positions: jax.Array | None = None):
     """Returns (last-position logits [B,Vpad], cache).  ``cache_len`` > prompt
-    pre-allocates decode headroom (engine never reallocates mid-stream)."""
+    pre-allocates decode headroom (engine never reallocates mid-stream).
+    ``positions`` [B,K] int32 returns the logits at those positions
+    instead, [B,K,Vpad] (causal: padding after a position leaves its
+    logits unchanged, so sequences of several lengths share one call).
+
+    ``dtype`` (dense/moe) computes in that dtype instead of the weights'
+    own: each layer's weights are cast inside the layer scan, so a
+    float32 forward of a bf16 model holds one layer's float32 copy at a
+    time (``Model.reference_prefill``)."""
     fam = cfg.family
     geom = head_geom(cfg, tp_size()) if cfg.n_heads else None
     tokens = batch["tokens"]
     bsz, s = tokens.shape
+    if dtype is not None and fam not in ("dense", "moe"):
+        raise ValueError(f"prefill dtype= supports dense/moe, got {fam}")
 
     if fam in ("dense", "moe"):
-        x = _res(embed_tokens(params["embed"], tokens))
+        x = _res(embed_tokens(_cast(params["embed"], dtype), tokens))
 
         def body(x, p):
+            p = _cast(p, dtype)
             h = rmsnorm(p["ln1"], x, cfg.norm_eps)
             a, k, v = _prefill_attn(cfg, p["attn"], h)
             x = x + a
@@ -313,9 +330,11 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict,
             lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
             cache["self"])
 
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = logits_from(params["embed"], cfg, x[:, -1:, :])[:, 0]
-    return logits, cache
+    x = (x[:, -1:, :] if positions is None
+         else jnp.take_along_axis(x, positions[:, :, None], axis=1))
+    x = rmsnorm(_cast(params["final_norm"], dtype), x, cfg.norm_eps)
+    logits = logits_from(_cast(params["embed"], dtype), cfg, x)
+    return (logits[:, 0] if positions is None else logits), cache
 
 
 # ================================================================== decode
@@ -534,13 +553,15 @@ def decode_chunk(cfg: ModelConfig, params: dict, cache: dict,
 def paged_cache_specs(cfg: ModelConfig, num_blocks: int,
                       block_size: int) -> dict[str, Any]:
     """Cache ParamSpec tree for the paged decode step: the KV lives in a
-    shared page pool ``(layers, num_blocks, block_size, kv, hd)`` instead
-    of dense per-slot rows.  Dense/moe only (attention caches)."""
+    shared page pool ``(layers, num_blocks, kv, block_size, hd)`` instead
+    of dense per-slot rows — head-major within a page, the layout the
+    Pallas kernel's K/V blocks tile (``kernels.paged_attention``).
+    Dense/moe only (attention caches)."""
     if cfg.family not in ("dense", "moe"):
         raise ValueError(f"paged cache supports dense/moe, got {cfg.family}")
     geom = head_geom(cfg, tp_size())
-    shape = (cfg.n_layers, num_blocks, block_size, geom.n_kv, geom.head_dim)
-    axes = ("layers", None, None, "cache_kv", None)
+    shape = (cfg.n_layers, num_blocks, geom.n_kv, block_size, geom.head_dim)
+    axes = ("layers", None, "cache_kv", None, None)
     return {"paged": {
         "k": P.ParamSpec(shape, axes, init="zeros"),
         "v": P.ParamSpec(shape, axes, init="zeros"),
@@ -549,7 +570,7 @@ def paged_cache_specs(cfg: ModelConfig, num_blocks: int,
 
 def decode_paged_chunk(cfg: ModelConfig, params: dict, cache: dict,
                        tokens: jax.Array, pos: jax.Array, n_new: jax.Array,
-                       page_table: jax.Array):
+                       page_table: jax.Array, *, attention: str):
     """C-token decode straight over the paged KV pool: the kernel-enabled
     serving engine's single step.
 
@@ -558,7 +579,8 @@ def decode_paged_chunk(cfg: ModelConfig, params: dict, cache: dict,
     ``{"paged": {"k", "v"}}`` — the shared page pool — and ``page_table``
     [B, n_pages] int32 maps each lane's logical blocks to physical
     pages.  Fresh KV rows are written through the table and attention
-    reads through it (``kernels.paged_attention``): no dense per-slot
+    reads through it (``kernels.paged_attention``, by the ``attention``
+    implementation: ``"pallas"`` or ``"reference"``): no dense per-slot
     working cache exists anywhere on this path.
     """
     fam = cfg.family
@@ -571,16 +593,18 @@ def decode_paged_chunk(cfg: ModelConfig, params: dict, cache: dict,
         x, kp, vp = carry
         p, i = xs
         h = rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, kp_i, vp_i = paged_chunk_decode_attention(
-            cfg, p["attn"], h, _idx(kp, i), _idx(vp, i),
-            page_table, pos, n_new)
+        # the whole pool rides the carry and the layer is an index into
+        # it: writes scatter in place, the kernel selects the layer
+        a, kp, vp = paged_chunk_decode_attention(
+            cfg, p["attn"], h, kp, vp, i, page_table, pos, n_new,
+            attention=attention)
         x = x + a
         h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
         if fam == "moe":
             y, _ = moe_ffn(cfg, p["moe"], h2)
         else:
             y = swiglu(p["mlp"], h2)
-        return (x + y, _upd(kp, kp_i, i), _upd(vp, vp_i, i)), None
+        return (x + y, kp, vp), None
 
     (x, ks, vs), _ = jax.lax.scan(
         body, (x, cache["paged"]["k"], cache["paged"]["v"]),

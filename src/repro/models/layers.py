@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import params as P
-from repro.parallel.ctx import constrain, shard_map_compat
+from repro.parallel.ctx import constrain
 
 
 def ceil_mult(x: int, m: int) -> int:
@@ -247,22 +247,6 @@ def _sp_ctx(x_shape):
     return ctx, tp, spec
 
 
-@jax.custom_jvp
-def opt_barrier(x: jax.Array) -> jax.Array:
-    """``jax.lax.optimization_barrier`` with a defined derivative (older
-    jax has no AD rules for the primitive).  The tangent passes through
-    un-barriered: identity is trivially transposable, and the barrier's
-    job here — pinning the convert below the gather — is a forward-pass
-    concern."""
-    return jax.lax.optimization_barrier(x)
-
-
-@opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return jax.lax.optimization_barrier(x), t
-
-
 def sp_gather_seq(x: jax.Array) -> jax.Array:
     """[B, S(seq-sharded over model), D] -> [B, S, D] replicated over
     model (bf16 all-gather; transpose = reduce-scatter)."""
@@ -273,11 +257,12 @@ def sp_gather_seq(x: jax.Array) -> jax.Array:
     out_spec = jax.sharding.PartitionSpec(spec[0], None, None)
 
     def body(xl):
-        return opt_barrier(
+        return jax.lax.optimization_barrier(
             jax.lax.all_gather(xl, "model", axis=1, tiled=True))
 
-    return bf16_tangent(shard_map_compat(
-        body, mesh=ctx.mesh, in_specs=(spec,), out_specs=out_spec)(x))
+    return bf16_tangent(jax.shard_map(
+        body, mesh=ctx.mesh, in_specs=(spec,), out_specs=out_spec,
+        check_vma=False)(x))
 
 
 def sp_col_projects(x: jax.Array, ws: tuple, features: tuple):
@@ -309,12 +294,12 @@ def sp_col_projects(x: jax.Array, ws: tuple, features: tuple):
         # barrier: stops XLA:CPU's bf16->f32 dot-operand promotion from
         # hoisting the convert above the gather (which would double the
         # wire bytes; TPU has native bf16 dots and no such promotion)
-        xf = opt_barrier(xf)
+        xf = jax.lax.optimization_barrier(xf)
         return tuple(xf @ w for w in wl)
 
-    outs = shard_map_compat(body, mesh=ctx.mesh,
-                            in_specs=(res_spec,) + w_specs,
-                            out_specs=out_specs)(x, *ws)
+    outs = jax.shard_map(body, mesh=ctx.mesh,
+                         in_specs=(res_spec,) + w_specs,
+                         out_specs=out_specs, check_vma=False)(x, *ws)
     return tuple(bf16_tangent(o) for o in outs)
 
 
@@ -337,10 +322,10 @@ def rs_project(h: jax.Array, w: jax.Array, feature: str) -> jax.Array:
     w_spec = jax.sharding.PartitionSpec("model", None)
 
     def body(hl, wl):
-        part = opt_barrier(hl @ wl)
+        part = jax.lax.optimization_barrier(hl @ wl)
         return jax.lax.psum_scatter(part.astype(hl.dtype), "model",
                                     scatter_dimension=1, tiled=True)
 
-    return bf16_tangent(shard_map_compat(
+    return bf16_tangent(jax.shard_map(
         body, mesh=ctx.mesh, in_specs=(h_spec, w_spec),
-        out_specs=out_spec)(h, w))
+        out_specs=out_spec, check_vma=False)(h, w))

@@ -49,6 +49,19 @@ class Model:
     def prefill(self, params: dict, batch: dict, cache_len: int | None = None):
         return D.prefill(self.cfg, params, batch, cache_len)
 
+    def reference_prefill(self, params: dict, tokens: jax.Array,
+                          positions: jax.Array | None = None):
+        """The reference served logits are held to: ``prefill``'s cache-free
+        forward over ``tokens`` [B,S] computed in float32 at full matmul
+        precision (a TPU runs float32 matmuls in bf16 passes otherwise),
+        with the same bf16 weights.  Returns float32 logits at the last
+        position [B,Vpad], or at ``positions`` [B,K] -> [B,K,Vpad].
+        Dense/moe."""
+        with jax.default_matmul_precision("highest"):
+            logits, _ = D.prefill(self.cfg, params, {"tokens": tokens},
+                                  dtype=jnp.float32, positions=positions)
+        return logits
+
     def decode_step(self, params: dict, cache: dict, token: jax.Array,
                     pos: jax.Array):
         return D.decode_step(self.cfg, params, cache, token, pos)
@@ -60,28 +73,32 @@ class Model:
     def decode_greedy_step(self, params: dict, cache: dict, token: jax.Array,
                            pos: jax.Array):
         """One-token decode with argmax fused into the jitted program:
-        returns (tokens [B] int32, new cache).  The all-greedy serving
-        fast path — only the selected token vector crosses to the host,
-        and none of the sampling pipeline (sort/softmax/cumsum) lowers."""
+        returns (tokens [B] int32, logits [B,Vpad] f32, new cache).  The
+        all-greedy serving fast path — only the selected token vector
+        crosses to the host (the logits stay on the device unless a
+        request asks for them), and none of the sampling pipeline
+        (sort/softmax/cumsum) lowers."""
         logits, cache = D.decode_step(self.cfg, params, cache, token, pos)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
     def decode_greedy_chunk(self, params: dict, cache: dict,
                             tokens: jax.Array, pos: jax.Array,
                             n_new: jax.Array):
-        """Chunked decode with fused argmax (paged engine, all-greedy)."""
+        """Chunked decode with fused argmax (paged engine, all-greedy):
+        returns (tokens [B] int32, logits [B,Vpad] f32, new cache).  The
+        logits stay on the device unless a request asks for them."""
         logits, cache = D.decode_chunk(self.cfg, params, cache, tokens, pos,
                                        n_new)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
     def decode_sample_step(self, params: dict, cache: dict, token: jax.Array,
                            pos: jax.Array, lane: dict):
         """One-token decode with sampling fused into the jitted program:
-        returns (tokens [B] int32, new cache).  ``lane`` is the per-slot
-        sampling state (serve.api.LaneState.as_args()); greedy lanes
-        (temperature 0) still get exact argmax."""
+        returns (tokens [B] int32, logits, new cache).  ``lane`` is the
+        per-slot sampling state (serve.api.LaneState.as_args()); greedy
+        lanes (temperature 0) still get exact argmax."""
         logits, cache = D.decode_step(self.cfg, params, cache, token, pos)
-        return D.sample_from_logits(logits, lane), cache
+        return D.sample_from_logits(logits, lane), logits, cache
 
     def decode_sample_chunk(self, params: dict, cache: dict,
                             tokens: jax.Array, pos: jax.Array,
@@ -89,32 +106,37 @@ class Model:
         """Chunked decode with fused sampling (the paged engine's step)."""
         logits, cache = D.decode_chunk(self.cfg, params, cache, tokens, pos,
                                        n_new)
-        return D.sample_from_logits(logits, lane), cache
+        return D.sample_from_logits(logits, lane), logits, cache
 
     def decode_paged_chunk(self, params: dict, cache: dict,
                            tokens: jax.Array, pos: jax.Array,
-                           n_new: jax.Array, page_table: jax.Array):
+                           n_new: jax.Array, page_table: jax.Array, *,
+                           attention: str):
         return D.decode_paged_chunk(self.cfg, params, cache, tokens, pos,
-                                    n_new, page_table)
+                                    n_new, page_table, attention=attention)
 
     def decode_paged_greedy_chunk(self, params: dict, cache: dict,
                                   tokens: jax.Array, pos: jax.Array,
-                                  n_new: jax.Array, page_table: jax.Array):
+                                  n_new: jax.Array, page_table: jax.Array, *,
+                                  attention: str):
         """Chunked decode over the paged KV pool with fused argmax — the
         kernel-enabled paged engine's all-greedy step.  KV reads and
-        writes both go through the page table; no dense working cache."""
+        writes both go through the page table; no dense working cache.
+        Returns (tokens, logits, cache) like :meth:`decode_greedy_chunk`."""
         logits, cache = D.decode_paged_chunk(self.cfg, params, cache, tokens,
-                                             pos, n_new, page_table)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+                                             pos, n_new, page_table,
+                                             attention=attention)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
     def decode_paged_sample_chunk(self, params: dict, cache: dict,
                                   tokens: jax.Array, pos: jax.Array,
                                   n_new: jax.Array, page_table: jax.Array,
-                                  lane: dict):
+                                  lane: dict, *, attention: str):
         """Chunked paged decode with fused sampling."""
         logits, cache = D.decode_paged_chunk(self.cfg, params, cache, tokens,
-                                             pos, n_new, page_table)
-        return D.sample_from_logits(logits, lane), cache
+                                             pos, n_new, page_table,
+                                             attention=attention)
+        return D.sample_from_logits(logits, lane), logits, cache
 
     def cache_specs(self, batch: int, seq_len: int):
         return D.cache_specs(self.cfg, batch, seq_len)

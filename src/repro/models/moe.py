@@ -20,8 +20,6 @@ from repro.configs.base import ModelConfig
 from repro.models import params as P
 from repro.parallel.ctx import _current
 
-from repro.parallel.ctx import shard_map_compat
-
 
 def moe_specs(cfg: ModelConfig, layers: int | None) -> dict:
     d, e, f = cfg.d_model, cfg.n_experts, cfg.d_ff
@@ -118,8 +116,8 @@ def moe_ffn(cfg: ModelConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Arr
     def wrapped(p_loc, x_loc):
         return body(p_loc, x_loc)
 
-    return shard_map_compat(
+    return jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(p_specs, x_spec),
-        out_specs=(x_spec, aux_spec),
+        out_specs=(x_spec, aux_spec), check_vma=False,
     )(p, x)

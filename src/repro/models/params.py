@@ -14,6 +14,7 @@ model's state is fully described independently of any host binding.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -71,7 +72,13 @@ def count(specs: Any) -> int:
     return total
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
+    """One leaf, generated under ``jit``: the float32 draw and its scaled
+    copy live only inside the program (fused into the bf16 output), so a
+    published-width leaf such as a stacked ``[32, 3072, 8192]`` MLP weight
+    holds one float32 draw and no second scaled float32 copy.  Same
+    values as the eager draw at the same key."""
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
@@ -85,7 +92,12 @@ def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
         )
         fan_in = max(int(np.prod([spec.shape[i] for i in fan_axes])), 1)
         scale = fan_in ** -0.5
-    return (jax.random.normal(key, spec.shape, jnp.float32) * scale).astype(spec.dtype)
+    # the barrier keeps XLA from folding ``scale`` into the draw's own
+    # constants, which would move a few elements across a bf16 rounding
+    # boundary: the scale-and-cast then fuses exactly as the eager ops do
+    draw = jax.lax.optimization_barrier(
+        jax.random.normal(key, spec.shape, jnp.float32))
+    return (draw * scale).astype(spec.dtype)
 
 
 def initialize(specs: Any, key: jax.Array) -> Any:

@@ -324,7 +324,6 @@ def mamba_block_cp(cfg: ModelConfig, p: dict, x: jax.Array, *,
                       jax.sharding.PartitionSpec(b_ax, None, None, None)))
     else:
         out_specs = x_spec
-    from repro.parallel.ctx import shard_map_compat
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(p_specs, x_spec),
-                          out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
+                       out_specs=out_specs, check_vma=False)
     return fn(p, x)

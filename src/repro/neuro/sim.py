@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from repro.neuro import cable
 from repro.neuro.ring import RingConfig, is_ring_head, source_of
 
-from repro.parallel.ctx import shard_map_compat
-
 
 @dataclass
 class SimResult:
@@ -113,9 +111,10 @@ def simulate(cfg: RingConfig, *, mesh=None, axis: str = "cells",
         spec = jax.sharding.PartitionSpec(axis)
         state_specs = cable.CellState(
             v=spec, m=spec, h=spec, n=spec, g_syn=spec)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             run, mesh=mesh, in_specs=(state_specs,),
-            out_specs=(state_specs, spec, jax.sharding.PartitionSpec()))
+            out_specs=(state_specs, spec, jax.sharding.PartitionSpec()),
+            check_vma=False)
     else:
         n_loc = cfg.n_cells
         fn = _run_local(cfg, n_loc, None, use_pallas)

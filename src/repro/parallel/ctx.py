@@ -67,28 +67,10 @@ def bind(mesh: Mesh, rules: dict):
 
 
 def mesh_of(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; older versions
-    default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """A mesh over the first ``prod(shape)`` devices with every axis
+    Auto-sharded (GSPMD propagates through the logical constraints)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at the
-    top level with ``check_vma``; older jax has the experimental module
-    with ``check_rep``.  Replication checks stay off either way (the
-    bodies use collectives XLA cannot always infer replication for)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def constrain(x: jax.Array, logical: Sequence[str | None]) -> jax.Array:

@@ -115,9 +115,24 @@ class LaneState:
         self.top_p[slot] = 1.0
 
     def as_args(self) -> dict[str, np.ndarray]:
-        return {"rid": self.rid, "step": self.step, "seed": self.seed,
-                "temperature": self.temperature, "top_k": self.top_k,
-                "top_p": self.top_p}
+        """Snapshots, never the live arrays: see :func:`host_snapshot`."""
+        return {"rid": self.rid.copy(), "step": self.step.copy(),
+                "seed": self.seed.copy(),
+                "temperature": self.temperature.copy(),
+                "top_k": self.top_k.copy(), "top_p": self.top_p.copy()}
+
+
+def host_snapshot(a: np.ndarray) -> np.ndarray:
+    """A private copy of a host buffer the engine keeps mutating.
+
+    On the CPU backend ``jnp.asarray(ndarray)`` may wrap the numpy buffer
+    without copying, and a dispatched step reads its inputs only when it
+    runs — after the previous (still pending) step.  Handing the engine's
+    live ``pos``/token/lane arrays to a step and then updating them for
+    the next one therefore changes the first step's inputs under it (the
+    contiguous engine's serial prefill produced wrong tokens this way).
+    Every host array passed to a jitted step is a snapshot."""
+    return np.array(a, copy=True)
 
 
 # ================================================================ protocol

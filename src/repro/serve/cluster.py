@@ -46,6 +46,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
+import jax
+
 from repro.audit.trace import NULL_TRACER, Tracer
 from repro.serve.api import GREEDY, RequestHandle, run_requests
 from repro.serve.engine import PagedServeEngine, Request, _validate
@@ -294,10 +296,17 @@ class ClusterEngine:
         if len(replica_tracers) != replicas:
             raise ValueError(f"need {replicas} replica tracers, "
                              f"got {len(replica_tracers)}")
+        # one replica per device where the host has enough of them: its
+        # weights are copied there and its page pool follows them
+        # (PagedServeEngine keeps the pool beside its params); with fewer
+        # devices every replica shares the default one
+        devices = jax.devices()
+        placed = ([jax.device_put(params, devices[i]) for i in range(replicas)]
+                  if len(devices) >= replicas > 1 else [params] * replicas)
         self._replicas = [
             _Replica(idx=i,
                      engine=PagedServeEngine(
-                         model, params, slots=slots, max_len=max_len,
+                         model, placed[i], slots=slots, max_len=max_len,
                          block_size=block_size, chunk=chunk,
                          tracer=replica_tracers[i], **engine_kwargs),
                      tracer=replica_tracers[i],
@@ -467,6 +476,7 @@ class ClusterEngine:
         cached = sum(rep["cached_tokens"] for rep in reps)
         shared_hit = cached / (prefill + cached) if prefill + cached else 0.0
         kernels = {rep["kernel"] for rep in reps}
+        attentions = {rep["attention"] for rep in reps}
         occ = [rep["mean_batch_occupancy"] for rep in reps]
         return {
             "engine": "cluster",
@@ -494,6 +504,8 @@ class ClusterEngine:
             "chunk": self.chunk,
             "prefix_cache": all(rep["prefix_cache"] for rep in reps),
             "kernel": kernels.pop() if len(kernels) == 1 else "mixed",
+            "attention": (attentions.pop() if len(attentions) == 1
+                          else "mixed"),
             "preemptions": sum(rep["preemptions"] for rep in reps),
             "routed": self.cstats.routed,
             "routed_affinity": round(self.cstats.routed_affinity, 3),

@@ -34,6 +34,7 @@ no shape polymorphism, no recompiles, no host-side logits traffic.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -44,10 +45,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.audit.trace import NULL_TRACER, Tracer
+from repro.kernels import ops as kops
 from repro.models.decode import CompileWatcher
 from repro.models.model import Model
 from repro.serve.api import (GREEDY, LaneState, RequestHandle, SamplingParams,
-                             run_requests)
+                             host_snapshot, run_requests)
 from repro.serve.paging import (BlockAllocator, DevicePageView, HostSwapPool,
                                 KVPool, PrefixCache, chain_hashes, pages_for)
 from repro.serve.scheduler import (DONE, PREEMPTED, RUNNING, WAITING, Plan,
@@ -67,7 +69,13 @@ class Request:
     eos_id: int = -1            # -1: never stops early
     priority: int = 0           # higher preempts lower on OOM (paged path)
     sampling: SamplingParams | None = None   # None => greedy
+    # keep_logits: the paged engines append the float32 logits row
+    # ([vocab_size]) each output token was chosen from to ``logits`` —
+    # how a caller holds served logits to a reference (one host copy of
+    # the [slots, vocab] step output per tick while such a request runs)
+    keep_logits: bool = False
     out: list[int] = field(default_factory=list)
+    logits: list[np.ndarray] = field(default_factory=list)
     finished: bool = False
     cancelled: bool = False
     t_submit: float = 0.0
@@ -105,22 +113,11 @@ def _samples(req: Request) -> bool:
     return not (req.sampling or GREEDY).greedy
 
 
-# Fixed-shape page movers for the swap tier.  The page/slot index is a
-# *traced* argument, so each helper compiles exactly once per pool shape;
-# eager ``.at[idx].set`` would bake the index (and the page count) into
-# the program and pay a fresh XLA compile on nearly every swap.
-@jax.jit
-def _read_page(pool, bid):
-    """One page ``(layers, block_size, kv, hd)`` out of the device pool."""
-    return jax.lax.dynamic_slice_in_dim(pool, bid, 1, axis=1)[:, 0]
-
-
-@jax.jit
-def _write_page(pool, bid, page):
-    return jax.lax.dynamic_update_slice_in_dim(pool, page[:, None], bid,
-                                               axis=1)
-
-
+# Fixed-shape slot movers for the gather pathway's swap tier (the paged
+# pool's page movers live on ``DevicePageView``).  The slot index is a
+# *traced* argument, so each helper compiles exactly once per cache
+# shape; eager ``.at[idx].set`` would bake the index into the program and
+# pay a fresh XLA compile on nearly every swap.
 @jax.jit
 def _read_slot(cache, slot):
     """One slot's dense rows ``(layers, max_len, kv, hd)`` (gather mode)."""
@@ -227,18 +224,13 @@ class ServeEngine:
         for i, tok in enumerate(tokens):
             self._last_token[slot, 0] = tok
             self.pos[slot] = i
-            if _samples(req) and i == len(tokens) - 1:
-                toks, self.cache = self._decode_sample(
-                    self.params, self.cache,
-                    jnp.asarray(self._last_token), jnp.asarray(self.pos),
-                    self.lane.as_args())
-            else:
-                toks, self.cache = self._decode(
-                    self.params, self.cache,
-                    jnp.asarray(self._last_token), jnp.asarray(self.pos))
+            toks, logits = self._dispatch(
+                _samples(req) and i == len(tokens) - 1)
         self.pos[slot] = len(tokens)
         nxt = int(np.asarray(toks)[slot])
         req.out.append(nxt)
+        if req.keep_logits:
+            req.logits.append(self._row(logits, slot))
         req.t_first = time.perf_counter()
         self._last_token[slot, 0] = nxt
         self.active[slot] = req
@@ -251,6 +243,23 @@ class ServeEngine:
                         slot=slot, consumed=len(tokens))
         self.trace.emit("first-token", rid=req.rid, tick=self.now,
                         ttft_ticks=self.now - arrival)
+
+    def _dispatch(self, sampled: bool) -> tuple[jax.Array, jax.Array]:
+        """One fused decode call on snapshots of the host-side lane
+        arrays (``host_snapshot``: the prefill loop mutates them before
+        the dispatched step has run); returns (tokens, logits)."""
+        tok = jnp.asarray(host_snapshot(self._last_token))
+        pos = jnp.asarray(host_snapshot(self.pos))
+        if sampled:
+            toks, logits, self.cache = self._decode_sample(
+                self.params, self.cache, tok, pos, self.lane.as_args())
+        else:
+            toks, logits, self.cache = self._decode(self.params, self.cache,
+                                                    tok, pos)
+        return toks, logits
+
+    def _row(self, logits: jax.Array, slot: int) -> np.ndarray:
+        return np.asarray(logits[slot, :self.model.cfg.vocab_size])
 
     def _retire(self, slot: int) -> Request:
         req = self.active.pop(slot)
@@ -292,17 +301,11 @@ class ServeEngine:
 
         if not self.active:
             return done
-        if any(_samples(r) for r in self.active.values()):
+        sampled = any(_samples(r) for r in self.active.values())
+        if sampled:
             for slot, req in self.active.items():
                 self.lane.set(slot, req)
-            toks, self.cache = self._decode_sample(
-                self.params, self.cache,
-                jnp.asarray(self._last_token), jnp.asarray(self.pos),
-                self.lane.as_args())
-        else:
-            toks, self.cache = self._decode(
-                self.params, self.cache,
-                jnp.asarray(self._last_token), jnp.asarray(self.pos))
+        toks, logits = self._dispatch(sampled)
         self.stats.decode_steps += 1
         self.stats.observe_occupancy(len(self.active))
         self.trace.emit("step", step_kind="decode", lanes=len(self.active))
@@ -312,6 +315,8 @@ class ServeEngine:
         for slot, req in self.active.items():
             tok = int(nxt[slot])
             req.out.append(tok)
+            if req.keep_logits:
+                req.logits.append(self._row(logits, slot))
             self.stats.tokens_out += 1
             self.pos[slot] += 1
             self._last_token[slot, 0] = tok
@@ -378,29 +383,47 @@ class ServeEngine:
 # ================================================================== paged
 
 
-def _chunk_fn_for(model: Model, sampled: bool, kernel: bool = False):
+def _chunk_fn_for(model: Model, sampled: bool, attention: str | None,
+                  pool_format=None):
     """One jitted chunk step per (Model instance, variant), shared by
     every engine built on it (benchmark sweeps construct many engines;
     recompiling per engine would dominate wall time).  Cached on the
     model itself so its lifetime — and the compiled executables' — ends
-    with the model.  Four variants on two axes: fused argmax for
-    all-greedy batches (the sampling pipeline never lowers) vs fused
-    sampling, and the paged-kernel step (KV through the page table) vs
-    the dense-working-cache step; jax.jit is lazy, so unused variants
-    never compile."""
-    attr = (f"_{'paged' if kernel else 'chunk'}"
-            f"_{'sample' if sampled else 'greedy'}_jit")
-    fn = getattr(model, attr, None)
+    with the model.  Variants on two axes: fused argmax for all-greedy
+    batches (the sampling pipeline never lowers) vs fused sampling, and
+    the paged step (KV through the page table, attended by the
+    ``attention`` implementation, bound statically; its pool pinned to
+    ``pool_format`` on the way in and out, one program per device) vs
+    the dense-working-cache step (``attention=None``); jax.jit is lazy,
+    so unused variants never compile."""
+    steps = model.__dict__.setdefault("_serve_steps", {})
+    key = (sampled, attention,
+           None if pool_format is None else pool_format.sharding)
+    fn = steps.get(key)
     if fn is None:
-        target = {
-            (False, False): model.decode_greedy_chunk,
-            (False, True): model.decode_sample_chunk,
-            (True, False): model.decode_paged_greedy_chunk,
-            (True, True): model.decode_paged_sample_chunk,
-        }[(kernel, sampled)]
-        fn = jax.jit(target, donate_argnums=(1,))
-        setattr(model, attr, fn)
+        if attention is None:
+            target = (model.decode_sample_chunk if sampled
+                      else model.decode_greedy_chunk)
+            fn = jax.jit(target, donate_argnums=(1,))
+        else:
+            target = functools.partial(
+                model.decode_paged_sample_chunk if sampled
+                else model.decode_paged_greedy_chunk, attention=attention)
+            pool = {"paged": {"k": pool_format, "v": pool_format}}
+            # (params, pool, tokens, pos, n_new, page_table[, lanes])
+            ins = (None, pool, None, None, None, None) + (
+                (None,) if sampled else ())
+            fn = jax.jit(target, donate_argnums=(1,), in_shardings=ins,
+                         out_shardings=(None, None, pool))
+        steps[key] = fn
     return fn
+
+
+def _device_of(params: Any):
+    """The one device the params live on (None if spread over several):
+    the paged engine keeps its page pool beside its weights."""
+    devs = jax.tree.leaves(params)[0].devices()
+    return next(iter(devs)) if len(devs) == 1 else None
 
 
 @dataclass
@@ -517,6 +540,12 @@ class PagedServeEngine:
         self.max_len = max_len
         self.chunk = chunk
         self.kernel = kernel
+        # which attention the jitted step lowers, decided once here and
+        # bound statically into the step (reported, never a trace-time
+        # branch): Pallas kernel on the TPU, page-table reference off it;
+        # the gather pathway attends its dense working cache
+        self.attention = (kops.paged_attention_impl() if kernel == "paged"
+                          else "dense")
         if num_blocks is None:
             num_blocks = 2 * slots * pages_for(max_len, block_size)
         self.alloc = BlockAllocator(num_blocks, block_size)
@@ -539,12 +568,13 @@ class PagedServeEngine:
             # comes from the declarative spec the jitted paged step is
             # written against, so the two cannot drift.
             spec = model.paged_cache_specs(num_blocks, block_size)
-            layers, _, _, n_kv, hd = spec["paged"]["k"].shape
+            layers, _, n_kv, _, hd = spec["paged"]["k"].shape
             self.pool = None
             self.view = DevicePageView(
                 num_blocks, block_size, layers, n_kv, hd,
                 spec["paged"]["k"].dtype,
-                slots=slots, max_pages=pages_for(max_len, block_size))
+                slots=slots, max_pages=pages_for(max_len, block_size),
+                device=_device_of(params))
             self.cache = self.view.cache()
         else:
             # dense-cache geometry without materializing it twice
@@ -583,12 +613,14 @@ class PagedServeEngine:
             self.trace.emit("compile", fn=fn, reason=reason, signature=sig)
 
         paged = kernel == "paged"
+        attention = self.attention if paged else None
+        fmt = self.view.format if paged else None
         self._chunk_fn = CompileWatcher(
-            _chunk_fn_for(model, sampled=False, kernel=paged),
+            _chunk_fn_for(model, False, attention, fmt),
             "decode_paged_chunk" if paged else "decode_chunk",
             on_compile=_on_compile)
         self._chunk_sample_fn = CompileWatcher(
-            _chunk_fn_for(model, sampled=True, kernel=paged),
+            _chunk_fn_for(model, True, attention, fmt),
             "decode_paged_sample_chunk" if paged else "decode_sample_chunk",
             on_compile=_on_compile)
         self.trace.emit("engine-init", engine="paged",
@@ -597,6 +629,7 @@ class PagedServeEngine:
                         chunk=chunk, pages=num_blocks,
                         prefix_cache=use_prefix_cache,
                         admit_every=admit_every, kernel=kernel,
+                        attention=self.attention,
                         preemption=preemption, swap=swap,
                         host_pages=host_blocks)
 
@@ -663,8 +696,7 @@ class PagedServeEngine:
     def _spill_page(self, bid: int) -> int | None:
         """PrefixCache spill hook: copy one device page's rows to the
         host tier (kernel mode; returns None when the tier is full)."""
-        hid = self.host.put(np.asarray(_read_page(self.view.k, bid)),
-                            np.asarray(_read_page(self.view.v, bid)))
+        hid = self.host.put(*self.view.read_page(bid))
         if hid is not None:
             self.trace.emit("swap-out", rid=None, tick=self.now,
                             reason="prefix-spill", pages=1,
@@ -680,9 +712,7 @@ class PagedServeEngine:
         if self.alloc.num_free == 0:
             return None
         bid = self.alloc.alloc()
-        k_rows, v_rows = self.host.get(hid)
-        self.view.k = _write_page(self.view.k, bid, jnp.asarray(k_rows))
-        self.view.v = _write_page(self.view.v, bid, jnp.asarray(v_rows))
+        self.view.write_page(bid, *self.host.get(hid))
         self.cache = self.view.cache()   # rebind: the writes made new arrays
         self.trace.emit("swap-in", rid=None, tick=self.now,
                         reason="prefix-restore", pages=1,
@@ -813,12 +843,8 @@ class PagedServeEngine:
         slot = self._free_slots()[0]
         n_pages = len(rec.host_ids)
         if self.kernel == "paged":
-            k, v = self.view.k, self.view.v
             for bid, hid in zip(private, rec.host_ids):
-                k_rows, v_rows = self.host.get(hid)
-                k = _write_page(k, bid, jnp.asarray(k_rows))
-                v = _write_page(v, bid, jnp.asarray(v_rows))
-            self.view.k, self.view.v = k, v
+                self.view.write_page(bid, *self.host.get(hid))
             self.cache = self.view.cache()   # rebind the fresh arrays
             table = list(private)
             self.view.bind_slot(slot, table)
@@ -912,10 +938,9 @@ class PagedServeEngine:
         bs = self.alloc.block_size
         n_pages = pages_for(st.consumed, bs)
         if self.kernel == "paged":
-            k_pages = np.stack([np.asarray(_read_page(self.view.k, b))
-                                for b in st.table[:n_pages]], axis=1)
-            v_pages = np.stack([np.asarray(_read_page(self.view.v, b))
-                                for b in st.table[:n_pages]], axis=1)
+            pages = [self.view.read_page(b) for b in st.table[:n_pages]]
+            k_pages = np.stack([k for k, _ in pages], axis=1)
+            v_pages = np.stack([v for _, v in pages], axis=1)
         else:
             rows = min(n_pages * bs, self.max_len)
             pad = ((0, 0), (0, n_pages * bs - rows), (0, 0), (0, 0))
@@ -1067,26 +1092,16 @@ class PagedServeEngine:
                 toks[slot, 0] = st.next_input
                 n_new[slot] = 1
 
+        args = [self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos), jnp.asarray(n_new)]
         if self.kernel == "paged":
-            pt = jnp.asarray(self.view.page_table)
-            if need_sample:
-                sampled, self.cache = self._chunk_sample_fn(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(n_new), pt,
-                    self.lane.as_args())
-            else:
-                sampled, self.cache = self._chunk_fn(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(n_new), pt)
+            args.append(jnp.asarray(host_snapshot(self.view.page_table)))
+        if need_sample:
+            args.append(self.lane.as_args())
+        fn = self._chunk_sample_fn if need_sample else self._chunk_fn
+        sampled, logits, self.cache = fn(*args)
+        if self.kernel == "paged":
             self.view.adopt(self.cache)
-        elif need_sample:
-            sampled, self.cache = self._chunk_sample_fn(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(n_new), self.lane.as_args())
-        else:
-            sampled, self.cache = self._chunk_fn(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(n_new))
         self.stats.decode_steps += 1
         self.stats.observe_occupancy(len(self.active))
         if self.trace.enabled:       # keep the untraced tick allocation-free
@@ -1101,6 +1116,9 @@ class PagedServeEngine:
                 prefill_tokens=sum(n for n, p in lanes if p),
                 chunk_sizes=tuple(n for n, _ in lanes))
         nxt = np.asarray(sampled)
+        rows = (np.asarray(logits)
+                if any(st.req.keep_logits for st in self.active.values())
+                else None)
 
         finished: list[int] = []
         for slot, st in self.active.items():
@@ -1119,6 +1137,8 @@ class PagedServeEngine:
                                 slot=slot, consumed=st.consumed)
             tok = int(nxt[slot])
             req.out.append(tok)
+            if req.keep_logits:
+                req.logits.append(rows[slot, :self.model.cfg.vocab_size])
             self.stats.tokens_out += 1
             if not req.t_first:
                 ttft = self.now - st.entry.arrival
@@ -1173,6 +1193,7 @@ class PagedServeEngine:
             "prefix_cache": self.prefix_enabled,
             "admit_every": self.admit_every,
             "kernel": self.kernel,
+            "attention": self.attention,
             "preemption": self.sched.preemption,
             "preemptions": self.sched.stats.preemptions,
             # host swap tier: the tiering pathway's health signals (the
@@ -1215,7 +1236,8 @@ def compare_engines(model: Model, params: Any,
                     chunk: int = 4, repeats: int = 1,
                     sampling: SamplingParams | None = None,
                     engine_kwargs: dict[str, dict] | None = None,
-                    cluster: dict | None = None):
+                    cluster: dict | None = None,
+                    logits_tol: float | None = None):
     """The paged engine's correctness proof, in the paper's methodology:
     the same workload under two environments (contiguous oracle vs paged)
     must agree token-for-token.  With ``sampling`` given, both engines
@@ -1238,33 +1260,48 @@ def compare_engines(model: Model, params: Any,
     This is the routing-correctness oracle: a router that corrupted,
     duplicated, or dropped a request would break bit-identity here.
 
+    ``logits_tol`` judges the served logits instead of token identity
+    (``core.verify.logits_verdict``): the verdict where the two
+    pathways may round differently (the contiguous engine's one-token
+    steps and the paged engine's chunks accumulate in different orders;
+    a cluster's batches differ from one engine's), so that a greedy
+    near-tie that parts the streams is not read as a fault.
+
     Returns a core.verify.DualEnvReport whose verdicts CI gates on."""
-    from repro.core.verify import DualEnvHarness
+    from repro.core.verify import DualEnvHarness, logits_verdict
 
     ek = engine_kwargs or {}
     contig_kw = dict(ek.get("contiguous", {}))
     paged_kw = dict(ek.get("paged", {}))
+    kept: dict[str, list[Request]] = {}
 
     def requests() -> list[Request]:
         reqs = make_requests()
-        if sampling is not None:
-            for r in reqs:
+        for r in reqs:
+            if sampling is not None:
                 r.sampling = sampling
+            r.keep_logits = logits_tol is not None
         return reqs
 
     probe = requests()
     n, max_new = len(probe), max(r.max_new for r in probe)
 
+    def result(name: str, done: list[Request]):
+        if logits_tol is None:
+            return token_matrix(done, n, max_new)
+        kept[name] = done
+        return None
+
     def run_contiguous():
         eng = ServeEngine(model, params, slots=slots, max_len=max_len,
                           **contig_kw)
-        return token_matrix(eng.run(requests()), n, max_new)
+        return result("contiguous", eng.run(requests()))
 
     def run_paged():
         eng = PagedServeEngine(model, params, slots=slots, max_len=max_len,
                                block_size=block_size, chunk=chunk,
                                **paged_kw)
-        return token_matrix(eng.run(requests()), n, max_new)
+        return result("paged", eng.run(requests()))
 
     harness = DualEnvHarness(repeats=repeats, warmup=0)
     if cluster is not None:
@@ -1277,9 +1314,16 @@ def compare_engines(model: Model, params: Any,
             eng = ClusterEngine(model, params, slots=slots, max_len=max_len,
                                 block_size=block_size, chunk=chunk,
                                 **cluster_kw)
-            return token_matrix(eng.run(requests()), n, max_new)
+            return result("cluster", eng.run(requests()))
 
-        return harness.compare("paged", run_paged,
-                               "cluster", run_cluster, rtol=1e-9, atol=0.5)
-    return harness.compare("contiguous", run_contiguous,
-                           "paged", run_paged, rtol=1e-9, atol=0.5)
+        names = ("paged", run_paged, "cluster", run_cluster)
+    else:
+        names = ("contiguous", run_contiguous, "paged", run_paged)
+    report = harness.compare(*names, rtol=1e-9, atol=0.5)
+    if logits_tol is not None:
+        a, b = ({r.rid: (r.out, r.logits) for r in kept[name]}
+                for name in names[::2])
+        report.verdicts.append(logits_verdict(
+            a, b, tol=logits_tol,
+            greedy=sampling is None or sampling.greedy))
+    return report

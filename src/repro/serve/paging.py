@@ -34,6 +34,7 @@ host tier) on both pathways.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -375,8 +376,10 @@ class HostSwapPool:
     their written pages here instead of discarding them (readmission swaps
     them back in, skipping the re-prefill), and cold prefix-cache pages
     evicted under allocator pressure spill here so a later match can page
-    them in.  Storage is per-page ``(layers, block_size, kv, hd)`` numpy
-    copies keyed by a monotonically increasing host id; entries are
+    them in.  Storage is per-page numpy copies in the engine's page
+    layout (``(layers, kv, block_size, hd)`` from the device pool,
+    ``(layers, block_size, kv, hd)`` from the gather pathway's dense
+    rows) keyed by a monotonically increasing host id; entries are
     refcounted like device pages so the property tests can assert the
     tier never leaks.
 
@@ -451,17 +454,60 @@ class HostSwapPool:
             assert len(self._store) <= self.capacity
 
 
+def pool_format(device) -> "Format":
+    """The device page pool's pinned layout: plain row-major on
+    ``device``.
+
+    Left to itself the TPU lays a ``[layers, num_blocks, kv, bs, hd]``
+    array out with ``num_blocks`` minor whenever ``hd`` is not a multiple
+    of 128 (that layout pads nothing), but the Pallas kernel reads
+    ``(bs, hd)`` tiles, so XLA would re-lay the whole pool out on the way
+    into and out of every step (the v5e compile rehearsal measured a
+    temporary the size of the pool).  Pinned row-major, the step updates
+    the donated pool in place; the price is lane padding of ``hd`` on the
+    chip (96 -> 128 for phi3-mini: 4/3 of the logical bytes)."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    return Format(Layout(major_to_minor=(0, 1, 2, 3, 4)),
+                  SingleDeviceSharding(device))
+
+
+@functools.lru_cache(maxsize=None)
+def _page_movers(fmt):
+    """Fixed-shape jitted page movers for one pool layout.  The page index
+    is a *traced* argument, so each compiles once per pool shape (eager
+    ``.at[idx].set`` would bake the index in and recompile on nearly
+    every swap); the write donates the pool and updates it in place."""
+    import jax
+
+    def read(pool, bid):
+        return jax.lax.dynamic_slice_in_dim(pool, bid, 1, axis=1)[:, 0]
+
+    def write(pool, bid, page):
+        return jax.lax.dynamic_update_slice_in_dim(pool, page[:, None], bid,
+                                                   axis=1)
+
+    return (jax.jit(read, in_shardings=(fmt, None)),
+            jax.jit(write, in_shardings=(fmt, None, None), out_shardings=fmt,
+                    donate_argnums=(0,)))
+
+
 class DevicePageView:
     """Device-resident page pool + per-slot page tables for the Pallas
     paged-attention kernel (``kernels.paged_attention``).
 
-    The pool arrays ``k``/``v`` — ``(layers, num_blocks, block_size, kv,
-    hd)`` — ARE the KV storage on the kernel path: the jitted paged step
-    writes fresh rows into them through the page table and attends every
-    page through the same table, so prefix sharing is pure metadata (a
-    shared page appears in many slots' table rows) and registration
-    copies nothing.  The arrays are donated to the step and re-adopted
-    from its output each tick (``cache()`` / ``adopt()``).
+    The pool arrays ``k``/``v`` — ``(layers, num_blocks, kv, block_size,
+    hd)``, head-major within a page as the Pallas kernel's K/V blocks
+    need, in the pinned row-major layout of :func:`pool_format` — ARE the
+    KV storage on the kernel path: the jitted paged step writes fresh
+    rows into them through the page table and attends every page through
+    the same table, so prefix sharing is pure metadata (a shared page
+    appears in many slots' table rows) and registration copies nothing.
+    The arrays are donated to the step and re-adopted from its output
+    each tick (``cache()`` / ``adopt()``); the swap tier moves single
+    pages ``(layers, kv, block_size, hd)`` with ``read_page`` /
+    ``write_page``.
 
     ``page_table`` is the host mirror the engine keeps in sync with the
     ``BlockAllocator``: ``bind_slot`` installs a slot's ordered physical
@@ -474,14 +520,25 @@ class DevicePageView:
 
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  n_kv: int, head_dim: int, dtype, *, slots: int,
-                 max_pages: int):
+                 max_pages: int, device=None):
+        import jax
         import jax.numpy as jnp
-        shape = (layers, num_blocks, block_size, n_kv, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+
+        shape = (layers, num_blocks, n_kv, block_size, head_dim)
+        self.format = pool_format(device or jax.devices()[0])
+        zeros = jax.jit(lambda: jnp.zeros(shape, dtype),
+                        out_shardings=self.format)
+        self.k = zeros()
+        self.v = zeros()
+        self._read, self._write = _page_movers(self.format)
         self.block_size = block_size
         self.max_pages = max_pages
         self.page_table = np.zeros((slots, max_pages), np.int32)
+
+    @property
+    def nbytes(self) -> int:
+        """Logical bytes of both pools (before any on-chip lane padding)."""
+        return self.k.nbytes + self.v.nbytes
 
     # ------------------------------------------------------------- tables
     def bind_slot(self, slot: int, blocks: Sequence[int]) -> None:
@@ -504,6 +561,18 @@ class DevicePageView:
         """Re-own the pool arrays returned by the jitted step."""
         self.k = cache["paged"]["k"]
         self.v = cache["paged"]["v"]
+
+    def read_page(self, bid: int) -> tuple[np.ndarray, np.ndarray]:
+        """One page's K and V rows, copied to the host."""
+        return (np.asarray(self._read(self.k, bid)),
+                np.asarray(self._read(self.v, bid)))
+
+    def write_page(self, bid: int, k_rows: np.ndarray,
+                   v_rows: np.ndarray) -> None:
+        """Overwrite one page in place (the pools are donated and
+        re-bound: callers holding ``cache()`` must take it again)."""
+        self.k = self._write(self.k, bid, k_rows)
+        self.v = self._write(self.v, bid, v_rows)
 
 
 def pages_for(n_tokens: int, block_size: int) -> int:

@@ -565,7 +565,7 @@ def test_sub_block_shared_prefix_does_not_false_positive():
     workload shared-prefix — a healthy run stays gate-clean."""
     from repro.launch.serve import serve
 
-    res = serve("deepseek-7b", n_requests=3, slots=2, max_len=48,
+    res = serve("deepseek-7b", reduced=True, n_requests=3, slots=2, max_len=48,
                 max_new=4, shared_prefix=4, block_size=8)
     assert res["audit"]["gate_ok"], res["audit"]["findings"]
 

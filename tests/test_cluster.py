@@ -372,3 +372,60 @@ def test_routed_affinity_vacuous_without_opportunities():
            "affine_opportunities": 0}
     assert ExpectationRegistry([rule]).evaluate(
         ctx, Evidence(engine_report=rep)) == []
+
+
+def test_cluster_logits_agree_with_single_engine(served, chat_trace):
+    """The logits form of the routing oracle (what the four-chip smoke
+    runs): every served row of the cluster equals the single engine's."""
+    _, model, params = served
+    for sp in (None, SamplingParams(temperature=0.8, top_k=16, seed=5)):
+        rep = compare_engines(model, params, lambda: _requests(chat_trace),
+                              cluster={"replicas": 3}, sampling=sp,
+                              logits_tol=1e-3, **GEOM)
+        (v,) = [v for v in rep.verdicts if v.kind == "logits"]
+        assert rep.ok and v.measured == 0.0, v.detail
+
+
+def test_replicas_take_one_device_each_when_the_host_has_enough():
+    """On a host with as many devices as replicas, replica i's weights
+    and page pool live on device i; cluster logits still match one
+    engine.  (Four placeholder CPU devices, in a child process.)"""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.configs import ALL_ARCHS, reduced
+        from repro.models import build
+        from repro.serve import ClusterEngine, Request, compare_engines
+        cfg = reduced(ALL_ARCHS["deepseek-7b"])
+        model = build(cfg)
+        params = model.init_params(jax.random.PRNGKey(0))
+        geom = dict(slots=2, max_len=48, block_size=8, chunk=4)
+        eng = ClusterEngine(model, params, replicas=4, **geom)
+        pools = [next(iter(r.view.k.devices())).id for r in eng.replicas]
+        weights = [next(iter(jax.tree.leaves(r.params)[0].devices())).id
+                   for r in eng.replicas]
+        assert pools == weights == [0, 1, 2, 3], (pools, weights)
+        rng = np.random.default_rng(0)
+        pre = rng.integers(0, cfg.vocab_size, size=16).tolist()
+        tails = [rng.integers(0, cfg.vocab_size, size=3 + i).tolist()
+                 for i in range(6)]
+        make = lambda: [Request(rid=i, prompt=pre + t, max_new=4)
+                        for i, t in enumerate(tails)]
+        rep = compare_engines(model, params, make, cluster={"replicas": 4},
+                              logits_tol=1e-3, **geom)
+        assert rep.ok, rep.summary()
+        print("OK")
+    """)
+    repo = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(repo / "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=repo, env=env, timeout=600)
+    assert out.returncode == 0 and out.stdout.strip().endswith("OK"), \
+        out.stderr[-3000:]

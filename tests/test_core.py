@@ -3,6 +3,7 @@ diagnostic-tool claim: seeded misconfigurations must be detected), verify,
 bootstrap, diagnostics."""
 import dataclasses
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -189,3 +190,93 @@ def test_diagnostics_gate():
     assert not d.gate()
     assert d.worst == "error"
     assert "error" in d.render()
+
+
+# ------------------------------------------- logits verdict, peaks, cache
+
+
+def _rows(tokens, *, v=6, seed=0):
+    """One logits row per token whose argmax is that token."""
+    rng = np.random.default_rng(seed)
+    rows = []
+    for t in tokens:
+        r = rng.standard_normal(v)
+        r[t] = r.max() + 1.0
+        rows.append(r)
+    return rows
+
+
+def test_logits_verdict_accepts_streams_parting_at_a_tie():
+    from repro.core.verify import logits_verdict
+
+    a_rows = _rows([1, 2, 3])
+    b_rows = [r.copy() for r in a_rows]
+    # the last row: tokens 3 and 4 within 1e-4 of each other, swapped
+    a_rows[2][4] = a_rows[2][3] - 1e-4
+    b_rows[2][4] = b_rows[2][3] + 1e-4
+    a = {0: ([1, 2, 3], a_rows)}
+    b = {0: ([1, 2, 4], b_rows)}
+    v = logits_verdict(a, b, tol=1e-3)
+    assert v.ok and v.kind == "logits" and "1 stream" in v.detail
+    # the same parting with rows further apart than the limit fails
+    b_rows[2] = b_rows[2] + 1.0
+    assert not logits_verdict(a, b, tol=1e-3).ok
+
+
+def test_logits_verdict_rejects_non_argmax_tokens_and_missing_requests():
+    from repro.core.verify import logits_verdict
+
+    rows = _rows([1, 2])
+    a = {0: ([1, 2], rows)}
+    # same rows, but run b emitted a token that is not its row's argmax
+    v = logits_verdict(a, {0: ([1, 5], rows)}, tol=1e-3)
+    assert not v.ok and "argmax" in v.detail
+    assert not logits_verdict(a, {}, tol=1e-3).ok
+    assert logits_verdict(a, {0: ([1, 2], [r + 1e-6 for r in rows])},
+                          tol=1e-3).ok
+
+
+def test_rel_l2_is_per_row_and_scale_free():
+    from repro.core.verify import rel_l2
+
+    want = np.array([[1.0, 0.0], [0.0, 100.0]])
+    got = np.array([[1.0, 0.1], [0.0, 100.0]])
+    assert rel_l2(got, want) == pytest.approx(0.1)
+    assert rel_l2(10 * got, 10 * want) == pytest.approx(0.1)
+
+
+def test_peaks_table_is_keyed_by_device_kind_and_refuses_unknowns():
+    from repro.core.manifest import HostBinding
+    from repro.core.peaks import chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
+    binding = HostBinding(device_kind="cpu", n_devices=1, mesh_shape=(1,),
+                          mesh_axes=("data",))
+    with pytest.raises(KeyError):
+        binding.peaks
+    # the binding carries no borrowed default peaks
+    assert not hasattr(HostBinding, "peak_flops")
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    from repro.core import bootstrap
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert bootstrap.setup_compile_cache() == "/elsewhere/cache"
+        # JAX's own handling of the variable stands: nothing set in code
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = bootstrap.setup_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert path == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -35,8 +35,8 @@ def _case(b, c, kv, g, hd, bs, n_pages, num_blocks, pos, n_new, *,
     logical order by accident), per-lane pos/n_new."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((b, c, kv, g, hd)), dtype)
-    kp = jnp.asarray(rng.standard_normal((num_blocks, bs, kv, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((num_blocks, bs, kv, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((num_blocks, kv, bs, hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((num_blocks, kv, bs, hd)), dtype)
     perm = rng.permutation(num_blocks)[:b * n_pages]
     pt = jnp.asarray(perm.reshape(b, n_pages).astype(np.int32))
     return (q, kp, vp, pt, jnp.asarray(pos, jnp.int32),
@@ -104,7 +104,7 @@ def test_single_token_sequence():
     q, kp, vp, pt, pos, n_new = args
     out = paged_attention_pallas(q, kp, vp, pt, pos, n_new, interpret=True)
     for b in range(2):
-        want = np.asarray(vp)[int(pt[b, 0]), 0]          # [kv, hd]
+        want = np.asarray(vp)[int(pt[b, 0]), :, 0]       # [kv, hd]
         got = np.asarray(out)[b, 0]                      # [kv, g, hd]
         np.testing.assert_allclose(got, np.repeat(
             want[:, None], got.shape[1], axis=1), rtol=2e-5, atol=2e-5)
@@ -159,8 +159,8 @@ def test_shared_prefix_pages_are_never_written():
     bs, c, nb = 8, 4, 6
     rng = np.random.default_rng(0)
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    kp = jnp.asarray(rng.standard_normal((nb, bs, kv, hd)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, kv, hd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((nb, kv, bs, hd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((nb, kv, bs, hd)), jnp.bfloat16)
     # both lanes share physical page 0 as their logical block 0; private
     # continuation pages 1 and 2 respectively
     pt = jnp.asarray(np.array([[0, 1, 0], [0, 2, 0]], np.int32))
@@ -169,15 +169,19 @@ def test_shared_prefix_pages_are_never_written():
     n_new = jnp.asarray([c, c], jnp.int32)
 
     before = {i: np.asarray(kp[i]).copy() for i in range(nb)}
-    _, kp2, vp2 = paged_chunk_decode_attention(cfg, p, x, kp, vp, pt,
-                                               pos, n_new)
-    after = np.asarray(kp2)
+    # a one-layer pool [1, nb, kv, bs, hd], layer 0
+    _, kp2, vp2 = paged_chunk_decode_attention(cfg, p, x, kp[None], vp[None],
+                                               0, pt, pos, n_new,
+                                               attention="reference")
+    after = np.asarray(kp2[0])
     # the shared page is bit-identical; each private page changed exactly
-    # its first c rows; everything else untouched
+    # its first c rows (of every kv head: pages are [kv, bs, hd]);
+    # everything else untouched
     assert (after[0] == before[0]).all(), "shared prefix page was written"
     for lane, page in ((0, 1), (1, 2)):
-        assert not (after[page][:c] == before[page][:c]).all()
-        assert (after[page][c:] == before[page][c:]).all()
+        for h in range(kv):
+            assert not (after[page][h, :c] == before[page][h, :c]).all()
+        assert (after[page][:, c:] == before[page][:, c:]).all()
     for untouched in (3, 4, 5):
         assert (after[untouched] == before[untouched]).all()
 
@@ -188,8 +192,8 @@ def test_two_slots_reading_shared_pages_agree_with_ref():
     b, c, kv, g, hd, bs, n_pages = 2, 2, 2, 2, 32, 8, 4
     rng = np.random.default_rng(21)
     q = jnp.asarray(rng.standard_normal((b, c, kv, g, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((10, bs, kv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((10, bs, kv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((10, kv, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((10, kv, bs, hd)), jnp.float32)
     # both lanes share pages 4 and 7 as blocks 0-1, then diverge
     pt = jnp.asarray(np.array([[4, 7, 1, 2], [4, 7, 5, 6]], np.int32))
     pos = jnp.asarray([2 * bs + 3, 3 * bs + 1], jnp.int32)
@@ -222,17 +226,17 @@ def test_kernel_through_engine_matches_gather_fallback():
                 for i in range(3)]
 
     def run(force_kernel):
-        # fresh Model per mode: the jitted paged program is cached on the
-        # model instance and bakes the dispatch decision in at trace time
-        model = build(cfg)
+        # the engine reads the attention choice once, at construction
         prev = ops.FORCE_PAGED_KERNEL
         ops.FORCE_PAGED_KERNEL = force_kernel
         try:
-            eng = PagedServeEngine(model, params, slots=2, max_len=48,
+            eng = PagedServeEngine(build(cfg), params, slots=2, max_len=48,
                                    block_size=8, chunk=4)
-            mat = token_matrix(eng.run(make()), 3, 4)
         finally:
             ops.FORCE_PAGED_KERNEL = prev
+        assert eng.report()["attention"] == (
+            "pallas" if force_kernel else "reference")
+        mat = token_matrix(eng.run(make()), 3, 4)
         eng.alloc.check()
         assert eng.pstats.cached_tokens > 0     # prefix reuse really on
         return mat
@@ -247,3 +251,27 @@ def test_kernel_through_engine_matches_gather_fallback():
     # and the ref-dispatch default (CPU) agrees too
     ref_mat = run(False)
     assert (ref_mat == gather_mat).all()
+
+
+def test_kernel_selects_the_layer_of_a_stacked_pool():
+    """The serving step hands the kernel the whole ``[layers, ...]`` pool
+    and a traced layer index: each layer attends its own pages."""
+    b, c, kv, g, hd, bs, n_pages, nb = 2, 2, 2, 2, 32, 8, 3, 8
+    layers = []
+    for layer in range(3):
+        args = _case(b, c, kv, g, hd, bs, n_pages, nb, pos=[5, 17],
+                     n_new=[2, 1], seed=40 + layer)
+        layers.append(args)
+    q, _, _, pt, pos, n_new = layers[0]
+    kp = jnp.stack([a[1] for a in layers])
+    vp = jnp.stack([a[2] for a in layers])
+    for layer in range(3):
+        got = jax.jit(lambda li: paged_attention_pallas(
+            q, kp, vp, pt, pos, n_new, layer=li, interpret=True))(layer)
+        want = paged_attention_ref(q, layers[layer][1], layers[layer][2],
+                                   pt, pos, n_new)
+        for lane in range(b):
+            n = int(n_new[lane])
+            np.testing.assert_allclose(np.asarray(got)[lane, :n],
+                                       np.asarray(want)[lane, :n],
+                                       rtol=2e-5, atol=2e-5)

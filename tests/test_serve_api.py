@@ -483,3 +483,65 @@ def test_lifecycle_events_feed_ttft_expectations(served):
     assert subs[1]["sampling"] != "greedy" and subs[0]["sampling"] == "greedy"
     [cancel] = tr.events("cancel")
     assert cancel.data["rid"] == 2
+
+
+# ------------------------------------------------------ served logits
+
+
+def test_keep_logits_records_the_row_each_token_came_from(served):
+    """Both engines append one float32 row per output token to requests
+    that ask (greedy: the token is that row's argmax), and nothing to
+    requests that do not."""
+    cfg, model, params = served
+    prompts = _prompts(cfg, n=3)
+    for eng in (_paged(model, params),
+                ServeEngine(model, params, slots=2, max_len=64)):
+        reqs = [Request(rid=i, prompt=list(p), max_new=5, keep_logits=i < 2)
+                for i, p in enumerate(prompts)]
+        done = {r.rid: r for r in eng.run(reqs)}
+        for rid in (0, 1):
+            r = done[rid]
+            assert len(r.logits) == len(r.out) == 5
+            assert all(row.shape == (cfg.vocab_size,)
+                       and row.dtype == np.float32 for row in r.logits)
+            assert [int(row.argmax()) for row in r.logits] == r.out
+        assert done[2].logits == []
+
+
+def test_contiguous_and_paged_logits_agree(served):
+    """The logits form of the dual-environment oracle, greedy and
+    sampled: rows agree within the bf16 pathway band."""
+    from repro.core.verify import BF16_LOGITS_TOL
+
+    cfg, model, params = served
+    prompts = _prompts(cfg)
+
+    def make():
+        return [Request(rid=i, prompt=list(p), max_new=6)
+                for i, p in enumerate(prompts)]
+
+    for sp in (None, SAMPLED):
+        rep = compare_engines(model, params, make, slots=2, max_len=64,
+                              block_size=8, chunk=4, sampling=sp,
+                              logits_tol=BF16_LOGITS_TOL)
+        (v,) = rep.verdicts
+        assert rep.ok and v.kind == "logits", v.detail
+
+
+def test_engine_reports_the_attention_its_step_lowered(served):
+    from repro.kernels import ops
+
+    cfg, model, params = served
+    assert ops.paged_attention_impl() == "reference"   # CPU, unforced
+    assert _paged(model, params).report()["attention"] == "reference"
+    assert _paged(model, params, kernel="gather").report()[
+        "attention"] == "dense"
+    prev = ops.FORCE_PAGED_KERNEL
+    ops.FORCE_PAGED_KERNEL = True
+    try:
+        assert _paged(model, params).report()["attention"] == "pallas"
+    finally:
+        ops.FORCE_PAGED_KERNEL = prev
+    with pytest.raises(ValueError, match="impl"):
+        ops.paged_attention(None, None, None, None, None, None,
+                            impl="interpret")

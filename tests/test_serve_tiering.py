@@ -26,7 +26,9 @@ def served():
 # ================================================== host swap pool units
 
 
-def _rows(fill, shape=(2, 4, 1, 3)):
+def _rows(fill, shape=(2, 1, 4, 3)):
+    # one device page: (layers, kv, block_size, hd), head-major as the
+    # paged pool lays it out
     return (np.full(shape, float(fill), np.float32),
             np.full(shape, -float(fill), np.float32))
 
