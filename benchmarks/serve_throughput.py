@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.audit import MetricSpec  # noqa: E402
+from repro.audit import MetricSpec, Tracer  # noqa: E402
 
 SPEEDUP_FLOOR = 1.3
 
@@ -189,13 +189,21 @@ def bench(arch: str = "deepseek-7b", *, smoke: bool = False,
     # -------- arrival-rate sweep on the paged path (synthetic tick clock)
     sweep = []
     for rate in rates:
+        tracer = Tracer()
         eng = PagedServeEngine(model, params, slots=slots, max_len=max_len,
-                               block_size=block, chunk=chunk)
+                               block_size=block, chunk=chunk, tracer=tracer)
         eng.run(warm())
         # the warm run advanced the tick clock and logged its own TTFTs;
-        # rewind so the sweep's arrival offsets mean what they say
+        # rewind so the sweep's arrival offsets mean what they say, and
+        # take the first-token latencies of the measured run only
         eng.now = 0.0
-        eng.ttft_ticks.clear()
+        ttft: list[float] = []
+
+        def on_first_token(ev, ttft=ttft):
+            if ev.kind == "first-token":
+                ttft.append(ev.data["ttft_ticks"])
+
+        tracer.subscribe(on_first_token)
         reqs = make()
         arrivals = [i / rate for i in range(len(reqs))]
         wall, tokens, _ = _timed_run(eng, reqs, arrivals)
@@ -203,8 +211,8 @@ def bench(arch: str = "deepseek-7b", *, smoke: bool = False,
         sweep.append({
             "arrival_rate_per_tick": rate,
             "tokens_per_s": round(tokens / max(wall, 1e-9), 1),
-            "mean_ttft_ticks": round(float(np.mean(eng.ttft_ticks)), 2)
-            if eng.ttft_ticks else None,
+            "mean_ttft_ticks": round(float(np.mean(ttft)), 2)
+            if ttft else None,
             "mean_batch_occupancy": rep["mean_batch_occupancy"],
             "prefix_hit_rate": rep["prefix_hit_rate"],
             "page_peak_utilization": rep["page_peak_utilization"],

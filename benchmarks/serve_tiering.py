@@ -164,7 +164,6 @@ def bench(arch: str = "deepseek-7b", *, smoke: bool = False, seed: int = 0,
                              0, cfg.vocab_size, 6).tolist(), max_new=2)
                  for i in range(slots)])
         eng.now = 0.0
-        eng.ttft_ticks.clear()
         wall, done = _timed_run(eng, make(), arrivals)
         return audit, eng, wall, token_matrix(done, n_req, low_max_new)
 

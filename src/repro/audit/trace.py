@@ -11,6 +11,16 @@ an unconditional ``tracer.emit(...)`` call and the disabled path costs
 one attribute lookup + empty call — no ``if tracer:`` branches sprinkled
 through engines.
 
+Spans land on the profiler's clock too: ``span`` opens a
+``jax.profiler.TraceAnnotation`` around its body, and ``phase`` is that
+annotation alone (no ring event, no count), for per-step phases whose
+events the ring's subscribers should not see.  While no profiler trace
+is being taken an annotation costs about a microsecond of host time;
+under ``jax.profiler.start_trace`` every span and phase is a host event
+aligned with the device's operations.  ``NULL_TRACER`` annotates as
+well, so a serving process captured with the profiler shows its phases
+whatever tracer it was given.
+
 Subscribers (``tracer.subscribe``) observe every event *at emission*,
 before the ring can drop it — the live-metrics layer
 (``audit.metrics``) is built on this: histograms and counters stay
@@ -25,17 +35,23 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 #: Every event kind the instrumented layers may emit.  The emit-kind
 #: lint (tests/test_audit.py) greps ``tracer.emit("...")`` /
-#: ``tracer.span("...")`` literals out of ``src/``, ``benchmarks/``,
-#: and ``scripts/`` and asserts
-#: they all appear here, so the metrics layer and the expectation
-#: registry can never silently miss a pathway because someone added an
-#: emitter without declaring its kind.
+#: ``tracer.span("...")`` / ``tracer.phase("...")`` literals out of
+#: ``src/``, ``benchmarks/``, and ``scripts/`` and asserts they all
+#: appear here, so the metrics layer and the expectation registry can
+#: never silently miss a pathway because someone added an emitter
+#: without declaring its kind.
 KNOWN_KINDS = frozenset({
     # serve.engine — request lifecycle + hot loop (both engines)
     "engine-init", "submit", "admit", "prefill-done", "first-token", "step",
     "preempt", "finish", "cancel", "compile",
+    # serve.engine — PagedServeEngine.step's phases (profiler annotations
+    # only, never ring events): the step, and its five contiguous parts
+    "serve.step", "serve.schedule", "serve.inputs", "serve.dispatch",
+    "serve.wait", "serve.commit",
     # serve.engine — KV memory tiering (device pool <-> host swap tier):
     # preempt/readmit page parking and cold-prefix spill/page-in
     "swap-out", "swap-in",
@@ -116,10 +132,21 @@ class Tracer:
         t0 = self.clock()
         extra: dict = {}
         try:
-            yield extra
+            with TraceAnnotation(kind):
+                yield extra
         finally:
             self.emit(kind, **{**data, **extra, "t_start": t0,
                                "dt_s": self.clock() - t0})
+
+    def phase(self, kind: str, /, step_num: int | None = None
+              ) -> TraceAnnotation:
+        """A profiler annotation alone: no ring event, no count, no
+        subscriber call, so the event stream is what it was without it.
+        With ``step_num`` it is a ``StepTraceAnnotation``, which marks a
+        step boundary for the profiler."""
+        if step_num is None:
+            return TraceAnnotation(kind)
+        return StepTraceAnnotation(kind, step_num=step_num)
 
     # -------------------------------------------------------------- query
     def events(self, kind: str | None = None) -> list[TraceEvent]:
@@ -167,7 +194,8 @@ class _NullTracer(Tracer):
 
     @contextmanager
     def span(self, kind: str, /, **data: Any) -> Iterator[dict]:  # noqa: ARG002
-        yield {}
+        with TraceAnnotation(kind):
+            yield {}
 
 
 NULL_TRACER = _NullTracer()

@@ -172,6 +172,7 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(layer, page_table, pos, n_new, q, k_pool, v_pool)
 
 

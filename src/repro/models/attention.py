@@ -368,8 +368,11 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     # pool out around the kernel call, twice per layer)
     heads = jnp.arange(kv)[None, None, :]
     page, off = page[:, :, None], off[:, :, None]
-    k_pool = k_pool.at[layer, page, heads, off].set(k_new, mode="drop")
-    v_pool = v_pool.at[layer, page, heads, off].set(v_new, mode="drop")
+    # named, so that the KV write is told apart from the kernel in a
+    # profile (its operations carry ``kv_append`` in their op_name)
+    with jax.named_scope("kv_append"):
+        k_pool = k_pool.at[layer, page, heads, off].set(k_new, mode="drop")
+        v_pool = v_pool.at[layer, page, heads, off].set(v_new, mode="drop")
 
     from repro.kernels import ops as kops
     out = kops.paged_attention(q, k_pool, v_pool, page_table, pos, n_new,

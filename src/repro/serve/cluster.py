@@ -457,7 +457,6 @@ class ClusterEngine:
             if r is req:
                 self._pending.pop(i)
                 req.cancelled = True
-                req.t_done = time.perf_counter()
                 self.cstats.cancelled_unrouted += 1
                 self.trace.emit("cancel", rid=req.rid, phase="waiting",
                                 tick=self.now, released_pages=0)

@@ -34,9 +34,7 @@ no shape polymorphism, no recompiles, no host-side logits traffic.
 """
 from __future__ import annotations
 
-import functools
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -54,12 +52,6 @@ from repro.serve.paging import (BlockAllocator, DevicePageView, HostSwapPool,
                                 KVPool, PrefixCache, chain_hashes, pages_for)
 from repro.serve.scheduler import (DONE, PREEMPTED, RUNNING, WAITING, Plan,
                                    SchedEntry, Scheduler, SwapCostModel)
-
-# quantile feeds (ttft_ticks) keep at most this many samples: a bounded
-# ring, not an unbounded per-request append, so a long-lived serving
-# process holds steady-state memory
-LATENCY_RING = 4096
-
 
 @dataclass
 class Request:
@@ -80,7 +72,6 @@ class Request:
     cancelled: bool = False
     t_submit: float = 0.0
     t_first: float = 0.0
-    t_done: float = 0.0
 
 
 def _validate(req: Request) -> None:
@@ -264,7 +255,6 @@ class ServeEngine:
     def _retire(self, slot: int) -> Request:
         req = self.active.pop(slot)
         req.finished = True
-        req.t_done = time.perf_counter()
         self.lane.clear(slot)
         self.trace.emit("finish", rid=req.rid, slot=slot, tick=self.now,
                         tokens_out=len(req.out))
@@ -352,7 +342,6 @@ class ServeEngine:
         if phase is None:
             return False
         req.cancelled = True
-        req.t_done = time.perf_counter()
         self.stats.cancelled += 1
         self.trace.emit("cancel", rid=req.rid, phase=phase, tick=self.now,
                         released_pages=0)
@@ -406,9 +395,19 @@ def _chunk_fn_for(model: Model, sampled: bool, attention: str | None,
                       else model.decode_greedy_chunk)
             fn = jax.jit(target, donate_argnums=(1,))
         else:
-            target = functools.partial(
-                model.decode_paged_sample_chunk if sampled
-                else model.decode_paged_greedy_chunk, attention=attention)
+            # named functions, so that the program and its operations
+            # carry a stable name (``jit_paged_greedy_step``) in HLO
+            # dumps and profiler traces
+            if sampled:
+                def paged_sample_step(*args):
+                    return model.decode_paged_sample_chunk(
+                        *args, attention=attention)
+                target = paged_sample_step
+            else:
+                def paged_greedy_step(*args):
+                    return model.decode_paged_greedy_chunk(
+                        *args, attention=attention)
+                target = paged_greedy_step
             pool = {"paged": {"k": pool_format, "v": pool_format}}
             # (params, pool, tokens, pos, n_new, page_table[, lanes])
             ins = (None, pool, None, None, None, None) + (
@@ -607,8 +606,6 @@ class PagedServeEngine:
         self.active: dict[int, _Slot] = {}
         self.stats = EngineStats()
         self.pstats = PagedStats()
-        # first-token latency, tick clock — bounded ring (quantile feed)
-        self.ttft_ticks: deque[float] = deque(maxlen=LATENCY_RING)
         def _on_compile(fn, reason, sig):
             self.trace.emit("compile", fn=fn, reason=reason, signature=sig)
 
@@ -988,7 +985,6 @@ class PagedServeEngine:
         if self.view is not None:
             self.view.clear_slot(slot)
         st.req.finished = True
-        st.req.t_done = time.perf_counter()
         self._release(st)
         self.trace.emit("finish", rid=st.req.rid, slot=slot, tick=self.now,
                         tokens_out=len(st.req.out),
@@ -1029,7 +1025,6 @@ class PagedServeEngine:
         else:
             return False
         req.cancelled = True
-        req.t_done = time.perf_counter()
         self.stats.cancelled += 1
         self.trace.emit("cancel", rid=req.rid, phase=phase, tick=self.now,
                         released_pages=released,
@@ -1039,9 +1034,44 @@ class PagedServeEngine:
     # --------------------------------------------------------------- step
     def step(self) -> list[Request]:
         """One engine tick: scheduler plan (every ``admit_every``-th
-        tick), then one fused chunked decode+sample call."""
+        tick), then one fused chunked decode+sample call.
+
+        The tick is the profiler step ``serve.step`` (``step_num`` = the
+        tick count), cut into five contiguous phases: ``serve.schedule``
+        (plan and admissions), ``serve.inputs`` (the step's host arrays
+        and their device copies), ``serve.dispatch`` (the jitted call
+        until it returns), ``serve.wait`` (the host blocked on the
+        sampled tokens) and ``serve.commit`` (tokens appended, blocks
+        registered, lanes finished)."""
         self.now += self.tick_dt
         self._ticks += 1
+        tr = self.trace
+        with tr.phase("serve.step", step_num=self._ticks):
+            with tr.phase("serve.schedule"):
+                if not self._schedule():
+                    return []
+            with tr.phase("serve.inputs"):
+                fn, args, n_new, counters = self._inputs()
+            with tr.phase("serve.dispatch"):
+                sampled, logits, self.cache = fn(*args)
+                if self.kernel == "paged":
+                    self.view.adopt(self.cache)
+                self.stats.decode_steps += 1
+                self.stats.observe_occupancy(len(self.active))
+                if counters is not None:
+                    tr.emit("step", **counters)
+            with tr.phase("serve.wait"):
+                nxt = np.asarray(sampled)
+                rows = (np.asarray(logits)
+                        if any(st.req.keep_logits
+                               for st in self.active.values())
+                        else None)
+            with tr.phase("serve.commit"):
+                return self._commit(n_new, nxt, rows)
+
+    def _schedule(self) -> bool:
+        """The scheduler's plan and every admission it makes; False when
+        no lane is left to run."""
         run_sched = (self._ticks - 1) % self.admit_every == 0
         admitted = 0
         if run_sched:
@@ -1074,8 +1104,13 @@ class PagedServeEngine:
                     "paged engine cannot place any waiting request: "
                     f"need more than {self.alloc.num_blocks} pages/"
                     f"{self.slots} slots")
-            return []
+            return False
+        return True
 
+    def _inputs(self) -> tuple[CompileWatcher, list, np.ndarray, dict | None]:
+        """The jitted step to call and its arguments, its ``n_new`` host
+        array, and the ``step`` event's payload (None when the tracer is
+        off)."""
         toks = np.zeros((self.slots, self.chunk), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         n_new = np.zeros((self.slots,), np.int32)
@@ -1098,28 +1133,36 @@ class PagedServeEngine:
             args.append(jnp.asarray(host_snapshot(self.view.page_table)))
         if need_sample:
             args.append(self.lane.as_args())
-        fn = self._chunk_sample_fn if need_sample else self._chunk_fn
-        sampled, logits, self.cache = fn(*args)
-        if self.kernel == "paged":
-            self.view.adopt(self.cache)
-        self.stats.decode_steps += 1
-        self.stats.observe_occupancy(len(self.active))
+        counters = None
         if self.trace.enabled:       # keep the untraced tick allocation-free
+            bs = self.alloc.block_size
             # lane kind comes from pending state, not chunk size: a
             # 1-token final prefill chunk is still a prefill lane
             lanes = [(int(n_new[s]), bool(st.pending))
                      for s, st in self.active.items()]
-            self.trace.emit(
-                "step", step_kind="chunk", tick=self.now, lanes=len(lanes),
+            counters = dict(
+                step_kind="chunk", tick=self.now, lanes=len(lanes),
                 prefill_lanes=sum(1 for _, p in lanes if p),
                 decode_lanes=sum(1 for _, p in lanes if not p),
                 prefill_tokens=sum(n for n, p in lanes if p),
-                chunk_sizes=tuple(n for n, _ in lanes))
-        nxt = np.asarray(sampled)
-        rows = (np.asarray(logits)
-                if any(st.req.keep_logits for st in self.active.values())
-                else None)
+                chunk_sizes=tuple(n for n, _ in lanes),
+                # what the program computes: (pos, n_new) of every running
+                # lane as passed to it, and the rows of its fixed shape
+                work=tuple((int(pos[s]), int(n_new[s])) for s in self.active),
+                rows=self.slots * self.chunk,
+                # pages the running lanes hold, and those their written
+                # rows fill, before this step writes
+                pages_bound=sum(len(st.shared) + len(st.private)
+                                for st in self.active.values()),
+                pages_written=sum(pages_for(st.consumed, bs)
+                                  for st in self.active.values()))
+        fn = self._chunk_sample_fn if need_sample else self._chunk_fn
+        return fn, args, n_new, counters
 
+    def _commit(self, n_new: np.ndarray, nxt: np.ndarray,
+                rows: np.ndarray | None) -> list[Request]:
+        """Each lane's sampled token appended, full blocks registered,
+        finished lanes retired and their pages freed."""
         finished: list[int] = []
         for slot, st in self.active.items():
             req, n = st.req, int(n_new[slot])
@@ -1141,11 +1184,9 @@ class PagedServeEngine:
                 req.logits.append(rows[slot, :self.model.cfg.vocab_size])
             self.stats.tokens_out += 1
             if not req.t_first:
-                ttft = self.now - st.entry.arrival
-                self.ttft_ticks.append(ttft)
                 req.t_first = time.perf_counter()
                 self.trace.emit("first-token", rid=req.rid, tick=self.now,
-                                ttft_ticks=ttft)
+                                ttft_ticks=self.now - st.entry.arrival)
             st.next_input = tok
             if (tok == req.eos_id or len(req.out) >= req.max_new
                     or st.consumed >= self.max_len - 1):

@@ -73,6 +73,37 @@ def test_null_tracer_records_nothing():
     assert not NULL_TRACER.enabled
 
 
+def test_spans_and_phases_land_on_the_profiler_clock(tmp_path):
+    """``span`` and ``phase`` annotate the profiler's host plane, on a
+    real tracer and on ``NULL_TRACER`` alike; only ``span`` records."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.phase("outer", step_num=7):
+            with tr.span("work"):
+                pass
+            with tr.phase("inner"):
+                pass
+        with NULL_TRACER.span("null-span"), NULL_TRACER.phase("null-phase"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    host = {e.name: dict(e.stats)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events}
+    assert {"outer", "work", "inner", "null-span", "null-phase"} <= set(host)
+    assert host["outer"]["step_num"] == 7
+    assert [e.kind for e in tr.events()] == ["work"]
+    assert tr.summary()["counts"] == {"work": 1}
+    assert NULL_TRACER.events() == [] and NULL_TRACER.emitted == 0
+
+
 def test_tracer_subscriber_sees_every_event_past_overflow():
     """The live-metrics feed contract: a subscriber observes the complete
     stream even after the bounded ring has evicted the early events."""
@@ -113,9 +144,10 @@ def test_tracer_spans_nest_across_overflow():
 
 def test_emitted_kinds_are_declared_in_known_kinds():
     """Emit-kind lint: every ``tracer.emit("...")`` / ``tracer.span("...")``
-    string literal in ``src/``, ``benchmarks/``, and ``scripts/`` must
-    appear in ``KNOWN_KINDS`` — a typo'd kind cannot silently create an
-    event stream nothing subscribes to, no matter which layer emits it."""
+    / ``tracer.phase("...")`` string literal in ``src/``, ``benchmarks/``,
+    and ``scripts/`` must appear in ``KNOWN_KINDS`` — a typo'd kind cannot
+    silently create an event stream nothing subscribes to, no matter which
+    layer emits it."""
     import ast
     from pathlib import Path
 
@@ -140,7 +172,8 @@ def test_emitted_kinds_are_declared_in_known_kinds():
             for node in ast.walk(tree):
                 if (isinstance(node, ast.Call)
                         and isinstance(node.func, ast.Attribute)
-                        and node.func.attr in ("emit", "span") and node.args):
+                        and node.func.attr in ("emit", "span", "phase")
+                        and node.args):
                     for kind in literal_kinds(node.args[0]):
                         found.setdefault(kind, []).append(
                             f"{path.relative_to(repo)}:{node.lineno}")
@@ -151,6 +184,8 @@ def test_emitted_kinds_are_declared_in_known_kinds():
     # including the cluster router's route events
     assert len(found) >= 15, sorted(found)
     assert "route" in found
+    # ... and the engine step's profiler phases
+    assert {"serve.step", "serve.wait"} <= set(found)
 
 
 # ---------------------------------------------------------- expectations
