@@ -6,16 +6,23 @@ gathered those pages into a dense per-slot working cache before every
 attention call — exactly the contiguous-shaped host detour the audit
 layer exists to flag.  This kernel consumes the paged layout directly:
 
-  * grid ``(slots, kv_heads, pages)`` with the page dimension sequential,
-    so the flash running max / denominator / accumulator live in VMEM
-    scratch across a slot's pages;
-  * the pool is head-major, ``[num_blocks, kv_heads, block_size, hd]``,
-    so one grid step's K/V block ``(1, 1, block_size, hd)`` has the
-    page's rows and the head dim as its last two (tiled) axes.  The
-    token-major layout ``[num_blocks, block_size, kv_heads, hd]`` needs a
-    block of 1 on the second-minor kv-head axis, which Mosaic refuses
-    (a second-minor block dim must be a multiple of 8 or the whole axis);
-    use a ``block_size`` that is a multiple of 8 (16 fills a bf16 tile);
+  * grid ``(slots, kv_heads // heads_per_block, pages)`` with the page
+    dimension sequential, so the flash running max / denominator /
+    accumulator live in VMEM scratch across a slot's pages.  One grid
+    step is one page of one lane for a block of kv heads — all of them
+    unless the blocks would not fit the VMEM budget
+    (:func:`heads_per_block`, from the shapes alone) — so the middle
+    axis is 1 for every configuration served today and a step's fixed
+    cost is paid once per page, not once per page and head;
+  * the pool is head-major, ``[layers, num_blocks, kv_heads, block_size,
+    hd]``, so one page of one layer for all its kv heads is one
+    contiguous ``(1, 1, kv, block_size, hd)`` block whose last two
+    (tiled) axes are the page's rows and the head dim; use a
+    ``block_size`` that is a multiple of 8 (16 fills a bf16 tile);
+  * queries and output are presented head-major with chunk and group
+    merged, ``[B, kv, C·G, hd]`` (the wrapper transposes in XLA), so the
+    tiled axes are ``(C·G, hd)``: ``(G, hd)`` tiles would pad every
+    query tile 8-16× at G = 1;
   * the page table rides scalar prefetch
     (``pltpu.PrefetchScalarGridSpec``): the K/V block index maps read
     ``page_table[slot, page]`` to fetch the *physical* page, which is
@@ -24,9 +31,12 @@ layer exists to flag.  This kernel consumes the paged layout directly:
   * per-lane sequence state (``pos`` rows already written, ``n_new``
     fresh rows this call) is prefetched too: ragged last pages and the
     causal chunk mask (query ``i`` sees positions ``<= pos + i``) are
-    masked inside the kernel, and pages past a lane's last valid row
-    issue no MXU work at all (the same block-skipping economics as the
-    causal flash kernel);
+    masked inside the kernel.  Pages past a lane's last valid row
+    (:func:`last_page`) do no MXU work, and the index maps clamp the
+    walk to that page: every later step names the block already held,
+    so the pipeline fetches nothing for it — the pages the engine
+    reserves at admission for a request's answer stay unread until
+    they are written;
   * one kernel covers the whole chunked-serving step: ``C`` queries per
     lane, so prefill chunks (``n_new > 1``), plain decode ticks
     (``n_new == 1``) and idle lanes (``n_new == 0``, outputs discarded)
@@ -47,11 +57,57 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: VMEM the kernel's blocks, scratch and score temporaries may take: the
+#: kv heads of one grid step are cut to fit it (:func:`heads_per_block`),
+#: with room left under the 16 MiB scoped VMEM of a v5e core.
+VMEM_BUDGET = 8 * 2**20
+
+
+def last_page(pos, n_new, block_size: int, n_pages: int):
+    """A lane's last page holding a row it attends, after this call's
+    ``n_new`` rows are written at ``pos``.  Idle lanes (``n_new == 0``)
+    count one row, so they visit page 0 and their discarded output stays
+    finite.  The kernel body and both K/V index maps
+    (:func:`kv_page_index`) share it."""
+    total = pos + jnp.maximum(n_new, 1)
+    return jnp.minimum((total - 1) // block_size, n_pages - 1)
+
+
+def kv_page_index(b, h, j, li, pt, pos, nn, *, block_size: int,
+                  n_pages: int):
+    """The K/V block index map: grid step ``(b, h, j)`` reads physical
+    page ``pt[b, j]`` of layer ``li[0]``, for kv-head block ``h``.  Past
+    the lane's last page the walk names that page again, so the pipeline
+    starts no copy for the rest of the walk."""
+    j = jnp.minimum(j, last_page(pos[b], nn[b], block_size, n_pages))
+    return (li[0], pt[b, j], h, 0, 0)
+
+
+def _tile(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a ``[rows, cols]`` VMEM array, padded to whole tiles."""
+    sublanes = 8 * (4 // itemsize)
+    return (-(-rows // sublanes) * sublanes * -(-cols // 128) * 128
+            * itemsize)
+
+
+def heads_per_block(kv: int, cg: int, bs: int, hd: int, q_bytes: int,
+                    kv_bytes: int) -> int:
+    """The largest divisor of ``kv`` whose heads' VMEM fits
+    :data:`VMEM_BUDGET`: double-buffered query, output, K and V blocks,
+    the f32 accumulator and running max / denominator, and the f32
+    score-shaped temporaries of the body."""
+    per_head = (4 * _tile(cg, hd, q_bytes) + 4 * _tile(bs, hd, kv_bytes)
+                + _tile(cg, hd, 4) + 2 * _tile(cg, 1, 4)
+                + 4 * _tile(cg, bs, 4))
+    fits = [d for d in range(1, kv + 1)
+            if kv % d == 0 and d * per_head <= VMEM_BUDGET]
+    return max(fits, default=1)
+
 
 def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
                   o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
-                  chunk: int, group: int, n_pages: int):
+                  group: int, n_pages: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -62,49 +118,41 @@ def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[b]
-    nn = nn_ref[b]
-    # rows valid for this lane after its chunk is written: idle lanes
-    # (nn == 0) still visit page 0 so the (discarded) output is finite
-    total = pos + jnp.maximum(nn, 1)
-    last = jnp.minimum((total - 1) // block_size, n_pages - 1)
+    last = last_page(pos, nn_ref[b], block_size, n_pages)
 
     @pl.when(j <= last)
     def _compute():
-        cg = chunk * group
-        hd = q_ref.shape[-1]
-        q = q_ref[0, :, 0].reshape(cg, hd).astype(jnp.float32)
-        k = k_ref[0, 0, 0].astype(jnp.float32)      # [bs, hd]
-        v = v_ref[0, 0, 0].astype(jnp.float32)
+        # every head of the block at once: batch dim 0 is the kv head
+        q = q_ref[0]                                 # [kvb, cg, hd]
+        k = k_ref[0, 0]                              # [kvb, bs, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [cg, bs]
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [kvb, cg, bs]
         # causal chunk mask on *physical* positions: query row i (rows
         # are [chunk, group] flattened) attends cache slots <= pos + i —
         # this both hides the ragged tail of the last page and keeps a
         # chunk causally exact against itself
         k_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (cg, block_size), 1)
-        row = jax.lax.broadcasted_iota(
-            jnp.int32, (cg, block_size), 0) // group
+            jnp.int32, s.shape, 2)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // group
         s = jnp.where(k_pos <= pos + row, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                          # [kvb, cg, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
+                            p, v, (((2,), (1,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
     @pl.when(j == last)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / denom[:, None]
-        o_ref[0, :, 0] = out.reshape(chunk, group,
-                                     acc_ref.shape[-1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
@@ -124,8 +172,8 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
                would make XLA materialize that slice for the custom call
                (the compile rehearsal measured a whole-pool temporary)
     page_table [B, n_pages] int32 — per-slot physical page indices; rows
-               past a slot's allocation must hold a valid index (0) —
-               they are masked, never out-of-bounds
+               past a slot's allocation must hold a valid index (0);
+               pages past a lane's last valid row are never read
     pos        [B] int32 — rows already in the cache per lane
     n_new      [B] int32 — fresh rows this call (0 = idle lane)
 
@@ -142,38 +190,45 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
     assert page_table.shape == (b, n_pages)
     scale = scale if scale is not None else hd ** -0.5
     layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    cg = c * g
+    kvb = heads_per_block(kv, cg, bs, hd, q.dtype.itemsize,
+                          k_pool.dtype.itemsize)
+    # [B, C, KV, G, hd] -> [B, KV, C*G, hd]: rows are (chunk, group)
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(b, kv, cg, hd)
+
+    kv_page = functools.partial(kv_page_index, block_size=bs,
+                                n_pages=n_pages)
+
+    def lane(b, h, j, li, pt, pos, nn):
+        return (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, kv, n_pages),
+        grid=(b, kv // kvb, n_pages),
         in_specs=[
-            pl.BlockSpec((1, c, 1, g, hd),
-                         lambda b, h, j, li, pt, pos, nn: (b, 0, h, 0, 0)),
+            pl.BlockSpec((1, kvb, cg, hd), lane),
             # the paged read: physical page via the prefetched table
-            pl.BlockSpec((1, 1, 1, bs, hd),
-                         lambda b, h, j, li, pt, pos, nn:
-                         (li[0], pt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bs, hd),
-                         lambda b, h, j, li, pt, pos, nn:
-                         (li[0], pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, kvb, bs, hd), kv_page),
+            pl.BlockSpec((1, 1, kvb, bs, hd), kv_page),
         ],
-        out_specs=pl.BlockSpec((1, c, 1, g, hd),
-                               lambda b, h, j, li, pt, pos, nn:
-                               (b, 0, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvb, cg, hd), lane),
         scratch_shapes=[
-            pltpu.VMEM((c * g,), jnp.float32),      # running max
-            pltpu.VMEM((c * g,), jnp.float32),      # running denominator
-            pltpu.VMEM((c * g, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((kvb, cg, 1), jnp.float32),      # running max
+            pltpu.VMEM((kvb, cg, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((kvb, cg, hd), jnp.float32),     # output accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                          chunk=c, group=g, n_pages=n_pages),
+                          group=g, n_pages=n_pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(layer, page_table, pos, n_new, q, k_pool, v_pool)
+    )(layer, page_table, pos, n_new, qh, k_pool, v_pool)
+    return out.reshape(b, kv, c, g, hd).transpose(0, 2, 1, 3, 4)
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new, *,

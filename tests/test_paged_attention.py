@@ -20,7 +20,10 @@ try:
 except ImportError:  # invariants still run via the conftest property loop
     from conftest import given, settings, st
 
-from repro.kernels.paged_attention import (paged_attention_pallas,
+from repro.configs import ALL_ARCHS
+from repro.kernels.paged_attention import (VMEM_BUDGET, heads_per_block,
+                                           kv_page_index, last_page,
+                                           paged_attention_pallas,
                                            paged_attention_ref)
 
 pytestmark = pytest.mark.kernel
@@ -88,6 +91,115 @@ def test_kernel_dtype_sweep(dtype, tol):
     args = _case(2, 4, 2, 2, 32, 8, 4, num_blocks=12,
                  pos=[13, 27], n_new=[4, 1], dtype=dtype, seed=7)
     _assert_parity(args, rtol=tol, atol=tol)
+
+
+def _served_case(kv, g, hd, c, *, bs=16, n_pages=6, seed=0):
+    """Three lanes as the engine leaves them, bf16 as the pool holds them:
+    an idle slot (cleared table row, pos 0, n_new 0), a lane whose last
+    row sits mid-page, and one whose last page is exactly full.  Each
+    running lane's row holds its written pages, then distinct pages
+    reserved at admission for its answer — never written, so NaN here —
+    then 0, a finite page.  Returns the arguments, and the same with the
+    reserved pages finite for the reference: its softmax gives those rows
+    weight 0, and 0 × NaN would poison its valid rows."""
+    rng = np.random.default_rng(seed)
+    ends = [2 * bs + 5, 3 * bs]                  # rows after this call
+    n_new = [0, c, c]
+    pos = [0] + [t - c for t in ends]
+    written = [t // bs + (t % bs > 0) for t in ends]
+    reserved = 2
+    nb = 1 + sum(written) + reserved * len(ends)
+    kp = rng.standard_normal((nb, kv, bs, hd)).astype(np.float32)
+    vp = rng.standard_normal((nb, kv, bs, hd)).astype(np.float32)
+    ids = iter(rng.permutation(np.arange(1, nb)))
+    pt = np.zeros((3, n_pages), np.int32)
+    kn, vn = kp.copy(), vp.copy()
+    for lane, w in zip((1, 2), written):
+        pt[lane, :w + reserved] = [next(ids) for _ in range(w + reserved)]
+        kn[pt[lane, w:w + reserved]] = np.nan
+        vn[pt[lane, w:w + reserved]] = np.nan
+    q = rng.standard_normal((3, c, kv, g, hd))
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)
+    lanes = (jnp.asarray(pt), jnp.asarray(pos, jnp.int32),
+             jnp.asarray(n_new, jnp.int32))
+    return ((bf(q), bf(kn), bf(vn)) + lanes,
+            (bf(q), bf(kp), bf(vp)) + lanes)
+
+
+def _assert_served_parity(served, finite):
+    """The kernel on the pool with NaN reserved pages: every output row
+    finite, and each lane's valid rows as the reference gives them on
+    the same pool with those pages finite."""
+    out = np.asarray(paged_attention_pallas(*served, interpret=True),
+                     np.float32)
+    assert np.isfinite(out).all()
+    ref = np.asarray(paged_attention_ref(*finite), np.float32)
+    n_new = np.asarray(served[5])
+    for b, n in enumerate(n_new.tolist()):
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], rtol=1e-2,
+                                   atol=1e-2, err_msg=f"lane {b}")
+
+
+@pytest.mark.parametrize("c", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("hd", [96, 128])
+@pytest.mark.parametrize("kv,g", [(4, 1), (8, 1), (2, 4), (4, 2)],
+                         ids=["mha-kv4", "mha-kv8", "gqa-kv2-g4",
+                              "gqa-kv4-g2"])
+def test_kernel_matches_reference_on_served_lanes(kv, g, hd, c):
+    """All kv heads of a page in one grid step: valid rows match the
+    reference, and the NaN pages reserved past a lane's last page reach
+    no output row (the clamp of the walk itself is pinned by
+    ``test_walk_is_clamped_to_each_lanes_last_page``)."""
+    _assert_served_parity(*_served_case(
+        kv, g, hd, c, seed=kv * 1000 + g * 100 + hd + c))
+
+
+def test_kv_heads_split_across_grid_steps_when_they_do_not_fit(monkeypatch):
+    """A budget that holds two of four kv heads splits the heads over the
+    grid's middle axis; the result is the same."""
+    from repro.kernels import paged_attention as pa
+
+    monkeypatch.setattr(pa, "VMEM_BUDGET", 400 * 2**10)
+    assert heads_per_block(4, 32, 16, 96, 2, 2) == 2
+    _assert_served_parity(*_served_case(4, 1, 96, 32, seed=77))
+
+
+def test_walk_is_clamped_to_each_lanes_last_page():
+    """The K/V index map names a lane's pages in order up to its last
+    one, and that last page for every later step of the walk, so the
+    pipeline fetches nothing past it (rows past the table's end clip to
+    its last page)."""
+    bs, n_pages = 16, 8
+    pt = jnp.arange(3 * n_pages, dtype=jnp.int32).reshape(3, n_pages) + 100
+    pos = jnp.array([0, 20, 100], jnp.int32)
+    nn = jnp.array([0, 1, 32], jnp.int32)
+    layer = jnp.array([3], jnp.int32)
+    lasts = last_page(pos, nn, bs, n_pages)
+    assert lasts.tolist() == [0, 1, n_pages - 1]
+    for b, last in enumerate(lasts.tolist()):
+        at = [tuple(int(i) for i in kv_page_index(
+            b, 0, j, layer, pt, pos, nn, block_size=bs, n_pages=n_pages))
+            for j in range(n_pages)]
+        for j in range(n_pages):
+            assert at[j] == (3, int(pt[b, min(j, last)]), 0, 0, 0)
+            if j > last:
+                assert at[j] == at[last]
+
+
+def test_every_config_folds_all_kv_heads_into_one_grid_step():
+    """The serving geometry (chunk 32, 16-row pages, bf16) of every
+    configuration fits all its kv heads in one block; a shape whose
+    heads would not fit is cut to the largest divisor that does."""
+    for cfg in ALL_ARCHS.values():
+        if not cfg.n_kv_heads:
+            continue
+        kv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        assert heads_per_block(kv, 32 * g, 16, cfg.resolved_head_dim,
+                               2, 2) == kv, cfg.name
+    # 512 query rows of hd 256 take about 3.1 MB a head: two fit 8 MiB
+    assert heads_per_block(48, 512, 16, 256, 2, 2) == 2
+    assert heads_per_block(1, 4096, 16, 256, 4, 4) == 1   # never below one
+    assert VMEM_BUDGET < 16 * 2**20
 
 
 # ------------------------------------------------------------------- edges

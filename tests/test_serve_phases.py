@@ -12,6 +12,7 @@ from jax.profiler import ProfileData
 
 from repro.audit.trace import KNOWN_KINDS, NULL_TRACER, Tracer
 from repro.configs import ALL_ARCHS, reduced
+from repro.kernels.paged_attention import last_page
 from repro.models import build
 from repro.serve import PagedServeEngine, Request
 from repro.serve.paging import pages_for
@@ -126,3 +127,32 @@ def test_step_counters_are_what_the_program_was_given(served):
                                               for c, _ in held.values())
     assert sum(n for e in tr.events("step") for _, n in e.data["work"]) == \
         sum(len(r.prompt) + len(r.out) - 1 for r in reqs)
+
+
+def test_pages_attended_is_each_slots_walk_to_its_last_page(served):
+    """The pages the attention kernel computes on follow from each
+    ``step`` event's ``work`` and ``lanes``: each running lane's walk up
+    to its :func:`last_page`, each idle slot's page 0.  Through
+    admission, prefill, decode, finishes and a refill of the freed slot
+    that walk covers every page the lanes have written and reads no page
+    they have not bound."""
+    cfg, model, params = served
+    tr = Tracer()
+    eng = _engine(model, params, tr)
+    max_pages = pages_for(64, BS)
+    _run(eng, _requests(cfg))
+    steps = tr.events("step")
+    assert steps
+    for e in steps:
+        ev = e.data
+        walk = [int(last_page(pos, n, BS, max_pages)) + 1
+                for pos, n in ev["work"]]
+        assert all(w == min(-(-(pos + max(n, 1)) // BS), max_pages)
+                   for w, (pos, n) in zip(walk, ev["work"]))
+        assert ev["pages_written"] <= sum(walk) <= ev["pages_bound"]
+        assert sum(walk) + SLOTS - ev["lanes"] <= SLOTS * max_pages
+    # the run covered every kind of step the walk has to follow
+    assert any(e.data["prefill_lanes"] for e in steps)
+    assert any(e.data["decode_lanes"] for e in steps)
+    assert any(e.data["lanes"] < SLOTS for e in steps)
+    assert tr.count("finish") == 3

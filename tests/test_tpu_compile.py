@@ -18,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import ALL_ARCHS
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (heads_per_block,
+                                           paged_attention_pallas)
 
 pytestmark = pytest.mark.kernel
 
@@ -74,10 +75,20 @@ def _compile_kernel(one_chip, *, slots, chunk, kv, group, hd, bs, pages,
     dict(kv=32, group=1, hd=96),
     # a GQA width: 8 kv heads of 4 query heads each, head_dim 128
     dict(kv=8, group=4, hd=128),
-], ids=["phi3-kv32-hd96", "gqa-kv8-g4-hd128"])
+    # the phi3-mini.decode-batch cell's own geometry (8 slots, 256 pages
+    # of a 640-page pool): the folded blocks' VMEM at the served size
+    dict(kv=32, group=1, hd=96, slots=8, pages=256, num_blocks=640),
+    # phi3 served at chunk 512 (``launch.serve --chunk``): 32 heads' blocks
+    # overrun the VMEM budget, so a grid step holds 2 of them
+    dict(kv=32, group=1, hd=96, chunk=512),
+], ids=["phi3-kv32-hd96", "gqa-kv8-g4-hd128", "phi3-cell-8slots-256pages",
+        "phi3-chunk512-split-heads"])
 def test_paged_kernel_compiles_for_v5e(one_chip, shape):
-    compiled = _compile_kernel(one_chip, slots=4, chunk=32, bs=16, pages=20,
-                               num_blocks=64, **shape)
+    geometry = dict(slots=4, chunk=32, bs=16, pages=20, num_blocks=64)
+    geometry |= shape
+    if geometry["chunk"] == 512:
+        assert heads_per_block(32, 512, 16, 96, 2, 2) == 2
+    compiled = _compile_kernel(one_chip, **geometry)
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -143,28 +154,32 @@ def test_paged_step_names_its_program_kernel_and_kv_write(paged_step):
     """The program is ``jit_paged_greedy_step``, the kernel's custom call
     is ``paged_attention``, and the KV write sits under ``kv_append``:
     no other instruction — the layer loop and the KV write above all —
-    carries a mark by which a profile's reader finds the kernel."""
+    carries a mark, in its own name or its op_name, by which a profile's
+    reader finds the kernel."""
     import re
 
     compiled, _ = paged_step
     text = compiled.as_text()
     assert text.startswith("HloModule jit_paged_greedy_step")
-    kernel_scope = re.compile(r'op_name="[^"]*/paged_attention/pallas_call"')
+    op_name = re.compile(r'op_name="([^"]*)"')
+    kernel_scope = "/paged_attention/pallas_call"
     marked, kv_append, calls = [], 0, 0
     for line in text.splitlines():
         head, eq, _ = line.strip().partition(" = ")
         if not eq:
             continue
+        name = head.removeprefix("ROOT ")
+        scope = op_name.search(line)
+        scope = scope.group(1) if scope else ""
         if 'custom_call_target="tpu_custom_call"' in line:
             calls += 1
-            assert head.removeprefix("ROOT ").startswith(
-                "%paged_attention"), head
-        elif any(m in line for m in KERNEL_MARKS):
-            marked.append(line.strip())
-        kv_append += "/kv_append/" in line
+            assert name.startswith("%paged_attention"), head
+        elif any(m in name or m in scope for m in KERNEL_MARKS):
+            marked.append((name, scope))
+        kv_append += "/kv_append/" in scope
     assert calls == 1 and kv_append
     # what else carries a mark is inside the kernel's own scope
-    assert all(kernel_scope.search(m) for m in marked), [
-        m[:200] for m in marked if not kernel_scope.search(m)]
+    assert all(s.endswith(kernel_scope) for _, s in marked), [
+        m for m in marked if not m[1].endswith(kernel_scope)]
     loops = [ln for ln in text.splitlines() if " while(" in ln]
     assert loops and not any(m in ln for ln in loops for m in KERNEL_MARKS)
