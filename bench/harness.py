@@ -29,6 +29,7 @@ import shutil
 import sys
 import time
 from dataclasses import dataclass, field
+from types import ModuleType
 
 import jax
 import numpy as np
@@ -62,6 +63,7 @@ class Step:
 class Window:
     """The record every metric reader reads."""
     config: dict
+    family: ModuleType            # the configuration's work counts
     setup_s: float
     t0: float
     t1: float
@@ -199,8 +201,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     reads them)."""
     log = log or (lambda m: print(m, file=sys.stderr, flush=True))
     cfg, mix, serving = cell.config, cell.mix, cell.config["serving"]
-    limit = min(serving["max_len"] - 1,
-                cfg.get("sliding_window") or serving["max_len"])
+    limit = cell.family.longest_context(cfg, serving)
     if traffic.longest_context(mix) > limit:
         raise ValueError(f"the mix can fill {traffic.longest_context(mix)} "
                          f"positions; the cell holds {limit}")
@@ -208,9 +209,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     peak = peaks.chip_peaks(dev.device_kind) if dev.platform == "tpu" else None
 
     marks = [("start", time.perf_counter())]
-    model = program.build_model(cfg)
+    model = program.build_model(cell.family.model_config(cfg))
     params = jax.block_until_ready(
-        program.make_params(model, cell.reference, cfg, seed))
+        program.make_params(model, cell.family, cell.reference, cfg, seed))
     marks.append(("weights drawn", time.perf_counter()))
     slots = serving["slots"]
     items = traffic.generate(mix, seed, cfg["vocab_size"], slots)
@@ -284,8 +285,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         f"pool's peak in use {report['page_peak_utilization']}, "
         f"{report['preemptions']} preemptions")
 
-    window = Window(config=cfg, setup_s=setup_s, t0=t0, t1=t1, slots=slots,
-                    recs=drv.recs, steps=drv.steps, peak=peak)
+    window = Window(config=cfg, family=cell.family, setup_s=setup_s, t0=t0,
+                    t1=t1, slots=slots, recs=drv.recs, steps=drv.steps,
+                    peak=peak)
     device = device_info(cell.chips)
     if traced:
         window.trace = tracing.reduce(*tracing.read(trace_dir))

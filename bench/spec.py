@@ -1,10 +1,12 @@
 """Finds a cell's parts by name, from ``BENCHMARK.json`` at the root of
 a checkout: the configuration file it names, the traffic mix
 ``bench/traffic/<traffic>.json``, the plain reference
-``bench/references/<reference>.py`` that the configuration names, and
-one reader ``bench/metrics/<metric>.py`` for each metric the cell
-reports.  Adding any of these is adding files and entries; nothing here
-changes."""
+``bench/references/<reference>.py`` and the family module
+``bench/families/<family>.py`` that the configuration names (its mapping
+onto the program and its work counts; ``bench/families/dense.py`` says
+what one gives), and one reader ``bench/metrics/<metric>.py`` for each
+metric the cell reports.  Adding any of these is adding files and
+entries; nothing here changes."""
 from __future__ import annotations
 
 import importlib.util
@@ -26,6 +28,7 @@ class Cell:
     metrics: list[dict]               # BENCHMARK.json metric entries
     readers: dict[str, ModuleType] = field(default_factory=dict)
     reference: ModuleType | None = None
+    family: ModuleType | None = None
 
 
 def load_module(path: Path) -> ModuleType:
@@ -61,4 +64,6 @@ def load(root: Path, workload: str, trace: bool) -> Cell:
         root / BENCH / "metrics" / f"{m['name']}.py") for m in metrics}
     cell.reference = load_module(
         root / BENCH / "references" / f"{config['reference']}.py")
+    cell.family = load_module(
+        root / BENCH / "families" / f"{config['family']}.py")
     return cell

@@ -4,6 +4,7 @@ from types import SimpleNamespace as NS
 import pytest
 
 from bench import spec
+from bench.families import dense
 from bench.tests.tiny import BENCH
 
 
@@ -53,16 +54,15 @@ PEAK = NS(bf16_flops=1e12, hbm_bw=1e9)
 
 
 def test_device_readers_divide_the_work_by_trace_time():
-    from bench import work
-
     steps = [NS(start=0.0, end=1.0, lanes=2, work=[(10, 1), (40, 8)])]
     trace = NS(window_s=2.0, time_of=lambda marks: 0.5)
-    w = _window(steps=steps, config=CFG, peak=PEAK, trace=trace)
-    flops = work.step_flops(CFG, steps[0].work)
+    w = _window(steps=steps, config=CFG, family=dense, peak=PEAK,
+                trace=trace)
+    flops = reader("step_mfu").step_flops(dense, CFG, steps[0].work)
     assert reader("step_mfu").read(w) == pytest.approx(
         100 * flops / (2.0 * 1e12))
-    need = max(work.paged_attn_flops(CFG, steps[0].work) / 1e12,
-               work.paged_attn_bytes(CFG, steps[0].work) / 1e9)
+    need = max(dense.paged_attn_flops(CFG, steps[0].work) / 1e12,
+               dense.paged_attn_bytes(CFG, steps[0].work) / 1e9)
     assert reader("paged_attn_roofline").read(w) == pytest.approx(
         100 * need / 0.5)
     # no kernel in the trace, or no traced step: no reading, never 0

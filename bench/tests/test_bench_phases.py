@@ -132,8 +132,9 @@ def test_step_events_carry_the_lane_work_the_harness_reads(tmp_path,
     engine_cls = serve.PagedServeEngine
     monkeypatch.setattr(serve, "PagedServeEngine",
                         lambda *a, **kw: engine_cls(*a, tracer=tr, **kw))
-    model = program.build_model(cfg)
-    params = program.make_params(model, cell.reference, cfg, 2**31 + 5)
+    model = program.build_model(cell.family.model_config(cfg))
+    params = program.make_params(model, cell.family, cell.reference, cfg,
+                                 2**31 + 5)
     engine = program.build_engine(model, params, serving)
     assert engine.trace is tr
     items = traffic.generate(cell.mix, 2**31 + 5, cfg["vocab_size"],

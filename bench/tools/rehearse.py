@@ -38,14 +38,17 @@ def main() -> None:
         cfg = json.loads((ROOT / entry["file"]).read_text())
         ref = spec.load_module(ROOT / "bench" / "references"
                                / f"{cfg['reference']}.py")
+        family = spec.load_module(ROOT / "bench" / "families"
+                                  / f"{cfg['family']}.py")
         sv = cfg["serving"]
-        model = program.build_model(cfg)
+        model = program.build_model(family.model_config(cfg))
 
         def place(tree, sharding=one):
             return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
                 a.shape, a.dtype, sharding=sharding), tree)
 
-        params = place(jax.eval_shape(model.init_params, jax.random.key(0)))
+        want = program.abstract_params(model)
+        params = place(want)
         cache = place(P.abstract(model.paged_cache_specs(
             sv["pool_pages"], sv["block_size"])), fmt)
         step = _chunk_fn_for(model, False, "pallas", fmt)
@@ -64,13 +67,7 @@ def main() -> None:
               f"{mem.alias_size_in_bytes} B, kernel "
               f"{'tpu_custom_call' in compiled.as_text()}", flush=True)
         # the weight-drawing program, as program.make_params jits it
-        import bench.weights as W
-        lt, ot = ref.layer_table(cfg), ref.outer_table(cfg)
-
-        def make(key):
-            return (W.layers(key, lt, jnp.arange(cfg["num_hidden_layers"])),
-                    W.outer(key, ot))
-
+        make = family.draw(ref, cfg, want)
         key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one)
         mem = jax.jit(make).lower(key).compile().memory_analysis()
         print(f"{cfg['name']} weights: outputs {mem.output_size_in_bytes} "
