@@ -81,6 +81,24 @@ ZAMBA2_2P7B = ModelConfig(
     source="arXiv:2411.15242; hf",
 )
 
+# Served through the paged engine only (``ModelConfig.paged_only``): the
+# sliding/full layer pattern, gated attention, sandwich norms and
+# sigmoid-routed experts with a shared one are computed by the paged
+# step alone.  Not an assigned architecture, so not in ``ALL_ARCHS``.
+TRINITY_MINI = ModelConfig(
+    name="trinity-mini", family="moe",
+    n_layers=32, d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+    d_ff=1024, vocab_size=200192, n_experts=128, top_k=8,
+    router="sigmoid", route_norm=True, route_scale=2.826,
+    n_shared_experts=1, n_dense_layers=2, d_ff_dense=6144,
+    sliding_window=2048, global_every=4, nope_global=True, attn_gate=True,
+    sandwich_norm=True, embed_scale=True, qk_norm=True,
+    rope_theta=10_000.0, norm_eps=1e-5,
+    source="hf:arcee-ai/Trinity-Mini (afmoe); hf",
+)
+
+SERVING_ARCHS: dict[str, ModelConfig] = {TRINITY_MINI.name: TRINITY_MINI}
+
 ALL_ARCHS: dict[str, ModelConfig] = {
     m.name: m
     for m in (

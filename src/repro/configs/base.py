@@ -45,6 +45,19 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # routing: "softmax" (top-k of the softmax, renormalised) or "sigmoid"
+    # (top-k of sigmoid scores plus a per-expert selection bias, weights
+    # the chosen scores, renormalised when ``route_norm``)
+    router: str = "softmax"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    n_shared_experts: int = 0  # always-on experts beside the routed ones
+    # the experts this chip holds of the routed ``n_experts`` (expert
+    # parallelism): ``experts_held`` from ``expert_offset`` on; 0 = all
+    experts_held: int = 0
+    expert_offset: int = 0
+    n_dense_layers: int = 0    # leading layers with a gated MLP, no experts
+    d_ff_dense: int = 0        # their width (``d_ff`` is the experts')
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -66,6 +79,13 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     qk_norm: bool = False      # qwen3-style per-head q/k RMSNorm
+    # --- attention pattern (afmoe) ---
+    sliding_window: int = 0    # keys q_pos - window < k_pos <= q_pos; 0 = none
+    global_every: int = 0      # every N-th layer attends the whole context
+    nope_global: bool = False  # whole-context layers take no RoPE
+    attn_gate: bool = False    # attention output x sigmoid(x @ wg)
+    sandwich_norm: bool = False  # RMSNorm after each branch, before its add
+    embed_scale: bool = False  # embedding x sqrt(d_model)
     dtype: str = "bfloat16"
     # ref: citation string from the assignment table
     source: str = ""
@@ -73,6 +93,31 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def layer_windows(self) -> tuple[int, ...]:
+        """Each layer's attention window (0: the whole context): with a
+        ``sliding_window``, every ``global_every``-th layer is whole and
+        the others windowed."""
+        if not self.sliding_window:
+            return (0,) * self.n_layers
+        return tuple(
+            0 if self.global_every and (i + 1) % self.global_every == 0
+            else self.sliding_window for i in range(self.n_layers))
+
+    @property
+    def paged_only(self) -> bool:
+        """True for a configuration only the paged serving step computes
+        (windows, output gates, sandwich norms and the rest of afmoe's
+        layer); the other paths raise rather than compute another model."""
+        return bool(self.sliding_window or self.attn_gate
+                    or self.sandwich_norm or self.embed_scale
+                    or self.n_dense_layers or self.n_shared_experts
+                    or self.router != "softmax"
+                    or self.held_experts != self.n_experts)
 
     @property
     def padded_vocab(self) -> int:
@@ -217,6 +262,11 @@ def reduced(model: ModelConfig, **overrides: Any) -> ModelConfig:
         decoder_train_len=16,
         attn_every=2,
         cross_every=2,
+        n_dense_layers=min(model.n_dense_layers, 1),
+        d_ff_dense=256 if model.d_ff_dense else 0,
+        experts_held=min(model.experts_held, 4) if model.experts_held else 0,
+        expert_offset=0,
+        sliding_window=min(model.sliding_window, 16),
         name=model.name + "-smoke",
     )
     small.update(overrides)

@@ -2,18 +2,22 @@
 from __future__ import annotations
 
 from repro.configs import ALL_ARCHS, SHAPES, applicable_shapes
+from repro.configs.archs import SERVING_ARCHS
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig, reduced
 
 
 def resolve_arch(name: str) -> ModelConfig:
-    if name in ALL_ARCHS:
-        return ALL_ARCHS[name]
+    """An assigned architecture (``ALL_ARCHS``) or one served through the
+    paged engine alone (``SERVING_ARCHS``)."""
+    known = {**ALL_ARCHS, **SERVING_ARCHS}
+    if name in known:
+        return known[name]
     # tolerate module-style ids (dots/dashes vs underscores)
     norm = name.replace("_", "-")
-    for k in ALL_ARCHS:
+    for k in known:
         if k.replace(".", "-") == norm or k == norm:
-            return ALL_ARCHS[k]
-    raise KeyError(f"unknown arch {name!r}; known: {sorted(ALL_ARCHS)}")
+            return known[k]
+    raise KeyError(f"unknown arch {name!r}; known: {sorted(known)}")
 
 
 def resolve_shape(name: str) -> ShapeConfig:

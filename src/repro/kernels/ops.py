@@ -63,15 +63,15 @@ def ssd_scan(x, dt, a, b_in, c_in, chunk: int):
 
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, n_new, *,
-                    layer=None, impl: str):
+                    layer=None, window=None, impl: str):
     """Decode/chunk attention through the device page table (the paged
     serving engine's hot path), by the named implementation."""
     if impl == "pallas":
         return paged_attention_pallas(q, k_pool, v_pool, page_table, pos,
-                                      n_new, layer=layer,
+                                      n_new, layer=layer, window=window,
                                       interpret=_interpret())
     if impl == "reference":
         return paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new,
-                                   layer=layer)
+                                   layer=layer, window=window)
     raise ValueError(f"paged attention impl must be one of "
                      f"{PAGED_ATTENTION_IMPLS}, got {impl!r}")

@@ -37,6 +37,10 @@ layer exists to flag.  This kernel consumes the paged layout directly:
     so the pipeline fetches nothing for it — the pages the engine
     reserves at admission for a request's answer stay unread until
     they are written;
+  * a windowed layer (``window``, a fifth prefetched scalar) starts the
+    walk at the window's first page (:func:`first_page`) as well: every
+    earlier step names that page, so nothing before the window is
+    fetched, and keys before a row's window are masked;
   * one kernel covers the whole chunked-serving step: ``C`` queries per
     lane, so prefill chunks (``n_new > 1``), plain decode ticks
     (``n_new == 1``) and idle lanes (``n_new == 0``, outputs discarded)
@@ -83,6 +87,23 @@ def kv_page_index(b, h, j, li, pt, pos, nn, *, block_size: int,
     return (li[0], pt[b, j], h, 0, 0)
 
 
+def first_page(pos, window, block_size: int):
+    """A lane's first page holding a row its window reaches: query row
+    ``i`` sees keys ``pos + i - window < k <= pos + i``, so no row sees
+    one before ``pos - window + 1``."""
+    return jnp.maximum(pos - window + 1, 0) // block_size
+
+
+def kv_page_index_windowed(b, h, j, li, win, pt, pos, nn, *,
+                           block_size: int, n_pages: int):
+    """:func:`kv_page_index` of a windowed layer (window ``win[0]``): the
+    walk also starts at the window's first page, and every earlier grid
+    step names that page, so no page before it is fetched."""
+    j = jnp.clip(j, first_page(pos[b], win[0], block_size),
+                 last_page(pos[b], nn[b], block_size, n_pages))
+    return (li[0], pt[b, j], h, 0, 0)
+
+
 def _tile(rows: int, cols: int, itemsize: int) -> int:
     """Bytes of a ``[rows, cols]`` VMEM array, padded to whole tiles."""
     sublanes = 8 * (4 // itemsize)
@@ -107,7 +128,7 @@ def heads_per_block(kv: int, cg: int, bs: int, hd: int, q_bytes: int,
 def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
                   o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
-                  group: int, n_pages: int):
+                  group: int, n_pages: int, win_ref=None):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -119,8 +140,12 @@ def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
 
     pos = pos_ref[b]
     last = last_page(pos, nn_ref[b], block_size, n_pages)
+    walked = j <= last
+    if win_ref is not None:
+        window = win_ref[0]
+        walked = walked & (j >= first_page(pos, window, block_size))
 
-    @pl.when(j <= last)
+    @pl.when(walked)
     def _compute():
         # every head of the block at once: batch dim 0 is the kv head
         q = q_ref[0]                                 # [kvb, cg, hd]
@@ -136,7 +161,10 @@ def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
         k_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // group
-        s = jnp.where(k_pos <= pos + row, s, NEG_INF)
+        seen = k_pos <= pos + row
+        if win_ref is not None:
+            seen = seen & (k_pos > pos + row - window)
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[...]                          # [kvb, cg, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -155,8 +183,16 @@ def _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, q_ref, k_ref, v_ref,
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
+def _windowed_kernel(layer_ref, win_ref, pt_ref, pos_ref, nn_ref, *refs,
+                     **kw):
+    """:func:`_paged_kernel` with the window's scalar prefetched second."""
+    _paged_kernel(layer_ref, pt_ref, pos_ref, nn_ref, *refs, win_ref=win_ref,
+                  **kw)
+
+
 def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
-                           layer=None, scale: float | None = None,
+                           layer=None, window=None,
+                           scale: float | None = None,
                            interpret: bool = False):
     """Decode/chunk attention over the paged KV pool.
 
@@ -176,6 +212,11 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
                pages past a lane's last valid row are never read
     pos        [B] int32 — rows already in the cache per lane
     n_new      [B] int32 — fresh rows this call (0 = idle lane)
+    window     None, or int32 scalar (traced): query row ``i`` of a lane
+               sees keys ``pos + i - window < k <= pos + i``, and the walk
+               starts at the window's first page (:func:`first_page`); a
+               window at least the lane's context is none.  ``None``
+               compiles the kernel without it
 
     Returns [B, C, KV, G, hd].  Rows ``>= n_new`` per lane are garbage
     the caller discards (same contract as ``chunk_decode_attention``).
@@ -196,14 +237,19 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
     # [B, C, KV, G, hd] -> [B, KV, C*G, hd]: rows are (chunk, group)
     qh = q.transpose(0, 2, 1, 3, 4).reshape(b, kv, cg, hd)
 
-    kv_page = functools.partial(kv_page_index, block_size=bs,
-                                n_pages=n_pages)
+    scalars = (layer, page_table, pos, n_new)
+    kernel, kv_map = _paged_kernel, kv_page_index
+    if window is not None:
+        scalars = (layer, jnp.reshape(jnp.asarray(window, jnp.int32), (1,)),
+                   page_table, pos, n_new)
+        kernel, kv_map = _windowed_kernel, kv_page_index_windowed
+    kv_page = functools.partial(kv_map, block_size=bs, n_pages=n_pages)
 
-    def lane(b, h, j, li, pt, pos, nn):
+    def lane(b, h, j, *scalars):
         return (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(scalars),
         grid=(b, kv // kvb, n_pages),
         in_specs=[
             pl.BlockSpec((1, kvb, cg, hd), lane),
@@ -219,7 +265,7 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, block_size=bs,
+        functools.partial(kernel, scale=scale, block_size=bs,
                           group=g, n_pages=n_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
@@ -227,16 +273,17 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, n_new, *,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(layer, page_table, pos, n_new, qh, k_pool, v_pool)
+    )(*scalars, qh, k_pool, v_pool)
     return out.reshape(b, kv, c, g, hd).transpose(0, 2, 1, 3, 4)
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new, *,
-                        layer=None, scale: float | None = None):
+                        layer=None, window=None,
+                        scale: float | None = None):
     """Pure-JAX oracle: dense gather *through the page table* + masked
     softmax.  Bitwise-independent of the kernel (full softmax instead of
     the online accumulation) but mathematically identical on valid rows.
-    Pools and ``layer`` as in :func:`paged_attention_pallas`."""
+    Pools, ``layer`` and ``window`` as in :func:`paged_attention_pallas`."""
     if k_pool.ndim == 5:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
     b, c, kv, g, hd = q.shape
@@ -252,7 +299,10 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, pos, n_new, *,
     s = jnp.einsum("bckgh,bskh->bkgcs", qf, k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
     idx = pos[:, None] + jnp.arange(c)[None, :]               # [B, C]
-    valid = jnp.arange(n_pages * bs)[None, None, :] <= idx[:, :, None]
+    k_pos = jnp.arange(n_pages * bs)[None, None, :]
+    valid = k_pos <= idx[:, :, None]
+    if window is not None:
+        valid = valid & (k_pos > idx[:, :, None] - window)
     s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgcs,bskh->bckgh", p, v.astype(jnp.float32))

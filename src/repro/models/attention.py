@@ -43,6 +43,8 @@ def attn_specs(cfg: ModelConfig, layers: int | None, *, kv_d: int | None = None)
     if cfg.qk_norm:
         specs["q_scale"] = P.scale(hd, layers)
         specs["k_scale"] = P.scale(hd, layers)
+    if cfg.attn_gate:
+        specs["wg"] = P.dense(d, h * hd, "embed", "heads_out", layers)
     return specs
 
 
@@ -308,7 +310,9 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
                                  k_pool: jax.Array, v_pool: jax.Array,
                                  layer: jax.Array, page_table: jax.Array,
                                  pos: jax.Array, n_new: jax.Array, *,
-                                 attention: str):
+                                 attention: str,
+                                 window: jax.Array | None = None,
+                                 use_rope: jax.Array | None = None):
     """Chunked decode directly over the paged KV pool — no dense per-slot
     working cache, no gather.
 
@@ -328,6 +332,13 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     kernel) or ``"reference"`` (the pure-JAX page-table reference), as
     chosen by ``kernels.ops.paged_attention_impl``.  The kernel runs per
     device; a tensor-parallel trace must ask for the reference.
+
+    A configuration with a ``sliding_window`` passes this layer's
+    ``window`` (traced int32; one at least the context is none) to the
+    attention, and one with ``nope_global`` its ``use_rope`` (traced
+    bool); with ``attn_gate`` the output is multiplied by
+    ``sigmoid(x @ wg)`` before ``wo``.  Without them the step is what it
+    was before they existed.
 
     Returns (y [B,C,D], new_k_pool, new_v_pool).
     """
@@ -349,8 +360,12 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     idx = pos[:, None] + jnp.arange(c)[None, :]            # [B,C]
     if cfg.rope_theta > 0:
         qf = q.reshape(b, c, kv * g, hd)
-        q = rope(qf, idx, cfg.rope_theta).reshape(b, c, kv, g, hd)
-        k_new = rope(k_new, idx, cfg.rope_theta)
+        q_r = rope(qf, idx, cfg.rope_theta).reshape(b, c, kv, g, hd)
+        k_r = rope(k_new, idx, cfg.rope_theta)
+        if cfg.nope_global:
+            q_r = jnp.where(use_rope, q_r, q)
+            k_r = jnp.where(use_rope, k_r, k_new)
+        q, k_new = q_r, k_r
 
     # masked scatter through the page table: row idx lands in physical
     # page ``table[idx // bs]`` at offset ``idx % bs`` (all heads).  Lanes
@@ -376,8 +391,11 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
 
     from repro.kernels import ops as kops
     out = kops.paged_attention(q, k_pool, v_pool, page_table, pos, n_new,
-                               layer=layer, impl=attention)
+                               layer=layer, window=window, impl=attention)
     out = out.reshape(b, c, kv * g * hd)
+    if cfg.attn_gate:
+        out = (out * jax.nn.sigmoid((x @ p["wg"]).astype(jnp.float32))
+               ).astype(out.dtype)
     out = constrain(out, ("act_batch", None, "act_heads"))
     y = out @ p["wo"]
     y = constrain(y, ("act_batch", None, None))

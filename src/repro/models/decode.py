@@ -22,9 +22,10 @@ from repro.models.attention import (chunk_decode_attention, decode_attention,
 from repro.models.layers import (embed_tokens, gelu_mlp, head_geom,
                                  logits_from, rmsnorm, sinusoidal_positions,
                                  swiglu)
-from repro.models.moe import moe_ffn
+from repro.models.moe import moe_ffn, moe_serve
 from repro.models.ssm import conv_channels, mamba_decode
-from repro.models.stack import _cross_block, _encoder, _res
+from repro.models.stack import (_cross_block, _encoder, _res,
+                                 refuse_paged_only)
 from repro.parallel.ctx import constrain
 
 
@@ -199,6 +200,7 @@ def prefill(cfg: ModelConfig, params: dict, batch: dict,
     float32 forward of a bf16 model holds one layer's float32 copy at a
     time (``Model.reference_prefill``)."""
     fam = cfg.family
+    refuse_paged_only(cfg, "prefill")
     geom = head_geom(cfg, tp_size()) if cfg.n_heads else None
     tokens = batch["tokens"]
     bsz, s = tokens.shape
@@ -359,6 +361,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     layers as stacked scan outputs instead double-buffers the whole cache
     (measured +2× cache bytes on the 32k cells)."""
     fam = cfg.family
+    refuse_paged_only(cfg, "decode_step")
     x = embed_tokens(params["embed"], token)
 
     if fam in ("dense", "moe"):
@@ -518,6 +521,7 @@ def decode_chunk(cfg: ModelConfig, params: dict, cache: dict,
     fam = cfg.family
     if fam not in ("dense", "moe"):
         raise ValueError(f"decode_chunk supports dense/moe caches, got {fam}")
+    refuse_paged_only(cfg, "decode_chunk")
     b = tokens.shape[0]
     x = embed_tokens(params["embed"], tokens)
 
@@ -582,39 +586,94 @@ def decode_paged_chunk(cfg: ModelConfig, params: dict, cache: dict,
     reads through it (``kernels.paged_attention``, by the ``attention``
     implementation: ``"pallas"`` or ``"reference"``): no dense per-slot
     working cache exists anywhere on this path.
+
+    A ``moe`` configuration's expert layers are :func:`moe.moe_serve`
+    (dropless, over the experts held here); its leading dense layers
+    (``n_dense_layers``) are a second layer stack, scanned first over the
+    same carried pool.  Each layer's window and RoPE flag ride the scan
+    as data (``ModelConfig.layer_windows``).
+
+    Returns (logits, cache, stats): ``stats`` is ``{}``, or where the
+    chip holds a share of the routed experts (``experts_held``)
+    ``{"experts": [rows routed to the held experts, the most one held
+    expert took]}`` int32, each summed over the expert layers.
     """
     fam = cfg.family
     if fam not in ("dense", "moe"):
         raise ValueError(f"decode_paged_chunk supports dense/moe, got {fam}")
-    b = tokens.shape[0]
+    b, c = tokens.shape
     x = embed_tokens(params["embed"], tokens)
+    if cfg.embed_scale:
+        x = (x.astype(jnp.float32) * cfg.d_model ** 0.5).astype(x.dtype)
+    windows = cfg.layer_windows()
+    n_pages = page_table.shape[1]
+    no_window = n_pages * cache["paged"]["k"].shape[3] + c
+    valid = jnp.arange(c)[None, :] < n_new[:, None]
+    layers, stack = params["layers"], None
+    if fam == "moe":
+        # the experts of every layer stay out of the scan and are indexed
+        # inside moe_serve's loop
+        moe_p = dict(layers["moe"])
+        stack = {k: moe_p.pop(k) for k in ("gate", "up", "down")}
+        layers = dict(layers, moe=moe_p)
 
-    def body(carry, xs):
-        x, kp, vp = carry
-        p, i = xs
+    def layer(x, kp, vp, p, i, w, r, ffn):
         h = rmsnorm(p["ln1"], x, cfg.norm_eps)
         # the whole pool rides the carry and the layer is an index into
         # it: writes scatter in place, the kernel selects the layer
         a, kp, vp = paged_chunk_decode_attention(
             cfg, p["attn"], h, kp, vp, i, page_table, pos, n_new,
-            attention=attention)
+            attention=attention, window=w, use_rope=r)
+        if cfg.sandwich_norm:
+            a = rmsnorm(p["ln1_post"], a, cfg.norm_eps)
         x = x + a
         h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if fam == "moe":
-            y, _ = moe_ffn(cfg, p["moe"], h2)
-        else:
-            y = swiglu(p["mlp"], h2)
-        return (x + y, kp, vp), None
+        y, st = ffn(p, h2, i)
+        if cfg.sandwich_norm:
+            y = rmsnorm(p["ln2_post"], y, cfg.norm_eps)
+        return x + y, kp, vp, st
 
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, cache["paged"]["k"], cache["paged"]["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+    def run(carry, ps, first: int, ffn):
+        """Scan one layer stack, the pool's layers ``first`` on."""
+        n = jax.tree.leaves(ps)[0].shape[0]
+        xs = (ps, jnp.arange(first, first + n))
+        if cfg.sliding_window:
+            ws = windows[first:first + n]
+            xs += (jnp.asarray([w or no_window for w in ws], jnp.int32),
+                   jnp.asarray([bool(w) or not cfg.nope_global for w in ws]))
+
+        def body(carry, xs):
+            x, kp, vp = carry
+            p, i, w, r = xs + (None,) * (4 - len(xs))
+            x, kp, vp, st = layer(x, kp, vp, p, i, w, r, ffn)
+            return (x, kp, vp), st
+
+        return jax.lax.scan(body, carry, xs)
+
+    def mlp(p, h, i):
+        return swiglu(p["mlp"], h), None
+
+    def experts(p, h, i):
+        return moe_serve(cfg, p["moe"], h, valid, stack,
+                         i - cfg.n_dense_layers)
+
+    carry = (x, cache["paged"]["k"], cache["paged"]["v"])
+    stats = {}
+    if fam == "dense":
+        carry, _ = run(carry, layers, 0, mlp)
+    else:
+        if cfg.n_dense_layers:
+            carry, _ = run(carry, params["dense_layers"], 0, mlp)
+        carry, st = run(carry, layers, cfg.n_dense_layers, experts)
+        if cfg.experts_held:
+            stats = {"experts": jnp.sum(st, axis=0)}
+    x, ks, vs = carry
 
     last = jnp.maximum(n_new, 1) - 1
     x_last = x[jnp.arange(b), last][:, None, :]
     x_last = rmsnorm(params["final_norm"], x_last, cfg.norm_eps)
     logits = logits_from(params["embed"], cfg, x_last)[:, 0]
-    return logits, {"paged": {"k": ks, "v": vs}}
+    return logits, {"paged": {"k": ks, "v": vs}}, stats
 
 
 # ============================================================ fused sampling

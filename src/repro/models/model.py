@@ -122,21 +122,26 @@ class Model:
         """Chunked decode over the paged KV pool with fused argmax — the
         kernel-enabled paged engine's all-greedy step.  KV reads and
         writes both go through the page table; no dense working cache.
-        Returns (tokens, logits, cache) like :meth:`decode_greedy_chunk`."""
-        logits, cache = D.decode_paged_chunk(self.cfg, params, cache, tokens,
-                                             pos, n_new, page_table,
-                                             attention=attention)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+        Returns (tokens, logits, cache) like :meth:`decode_greedy_chunk`,
+        and where the step counts anything (a chip's share of experts;
+        :func:`decode.decode_paged_chunk`) those counts fourth."""
+        logits, cache, stats = D.decode_paged_chunk(
+            self.cfg, params, cache, tokens, pos, n_new, page_table,
+            attention=attention)
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+        return out + (stats,) if stats else out
 
     def decode_paged_sample_chunk(self, params: dict, cache: dict,
                                   tokens: jax.Array, pos: jax.Array,
                                   n_new: jax.Array, page_table: jax.Array,
                                   lane: dict, *, attention: str):
-        """Chunked paged decode with fused sampling."""
-        logits, cache = D.decode_paged_chunk(self.cfg, params, cache, tokens,
-                                             pos, n_new, page_table,
-                                             attention=attention)
-        return D.sample_from_logits(logits, lane), logits, cache
+        """Chunked paged decode with fused sampling; returns as
+        :meth:`decode_paged_greedy_chunk`."""
+        logits, cache, stats = D.decode_paged_chunk(
+            self.cfg, params, cache, tokens, pos, n_new, page_table,
+            attention=attention)
+        out = D.sample_from_logits(logits, lane), logits, cache
+        return out + (stats,) if stats else out
 
     def cache_specs(self, batch: int, seq_len: int):
         return D.cache_specs(self.cfg, batch, seq_len)

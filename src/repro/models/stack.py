@@ -37,22 +37,31 @@ from repro.parallel.ctx import constrain
 # ===================================================================== specs
 
 
-def _dense_layer_specs(cfg: ModelConfig, n: int) -> dict:
-    return {
+def _sandwich(cfg: ModelConfig, n: int, specs: dict) -> dict:
+    """The norms after each branch, before its residual add (afmoe)."""
+    if cfg.sandwich_norm:
+        specs["ln1_post"] = rmsnorm_spec(cfg.d_model, n)
+        specs["ln2_post"] = rmsnorm_spec(cfg.d_model, n)
+    return specs
+
+
+def _dense_layer_specs(cfg: ModelConfig, n: int,
+                       d_ff: int | None = None) -> dict:
+    return _sandwich(cfg, n, {
         "ln1": rmsnorm_spec(cfg.d_model, n),
         "attn": attn_specs(cfg, n),
         "ln2": rmsnorm_spec(cfg.d_model, n),
-        "mlp": swiglu_specs(cfg, n),
-    }
+        "mlp": swiglu_specs(cfg, n, d_ff),
+    })
 
 
 def _moe_layer_specs(cfg: ModelConfig, n: int) -> dict:
-    return {
+    return _sandwich(cfg, n, {
         "ln1": rmsnorm_spec(cfg.d_model, n),
         "attn": attn_specs(cfg, n),
         "ln2": rmsnorm_spec(cfg.d_model, n),
         "moe": moe_specs(cfg, n),
-    }
+    })
 
 
 def _ssm_layer_specs(cfg: ModelConfig, n: int) -> dict:
@@ -98,7 +107,12 @@ def param_specs(cfg: ModelConfig) -> dict:
     if fam == "dense":
         specs["layers"] = _dense_layer_specs(cfg, cfg.n_layers)
     elif fam == "moe":
-        specs["layers"] = _moe_layer_specs(cfg, cfg.n_layers)
+        # leading dense layers, then the expert layers: two stacks
+        specs["layers"] = _moe_layer_specs(
+            cfg, cfg.n_layers - cfg.n_dense_layers)
+        if cfg.n_dense_layers:
+            specs["dense_layers"] = _dense_layer_specs(
+                cfg, cfg.n_dense_layers, cfg.d_ff_dense)
     elif fam == "ssm":
         specs["layers"] = _ssm_layer_specs(cfg, cfg.n_layers)
     elif fam == "hybrid":
@@ -129,7 +143,9 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     total = P.count(param_specs(cfg))
     if active_only and cfg.n_experts and cfg.top_k:
         expert = 3 * cfg.d_model * cfg.d_ff  # gate+up+down per expert
-        total -= cfg.n_layers * expert * (cfg.n_experts - cfg.top_k)
+        held = cfg.held_experts
+        total -= ((cfg.n_layers - cfg.n_dense_layers) * expert
+                  * (held - min(cfg.top_k, held)))
     return total
 
 
@@ -140,6 +156,16 @@ def nonembedding_param_count(cfg: ModelConfig, active_only: bool = False) -> int
 
 
 # ================================================================= forward
+
+
+def refuse_paged_only(cfg: ModelConfig, path: str) -> None:
+    """Raise where ``path`` would compute another model than ``cfg``'s
+    (``ModelConfig.paged_only``)."""
+    if cfg.paged_only:
+        raise ValueError(
+            f"{path} does not compute {cfg.name}'s layer (windows, output "
+            f"gates, sandwich norms, held or sigmoid-routed experts): "
+            f"serve it through PagedServeEngine's paged step")
 
 
 def _remat(fn, mode: str):
@@ -210,6 +236,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict, *,
     """Full-sequence forward -> (logits [B,S,Vpad], metrics)."""
     fam = cfg.family
     metrics: dict[str, jax.Array] = {}
+    refuse_paged_only(cfg, "forward")
 
     if fam == "encdec":
         return _encdec_forward(cfg, params, batch, remat)

@@ -46,6 +46,7 @@ from repro.audit.trace import NULL_TRACER, Tracer
 from repro.kernels import ops as kops
 from repro.models.decode import CompileWatcher
 from repro.models.model import Model
+from repro.models.stack import refuse_paged_only
 from repro.serve.api import (GREEDY, LaneState, RequestHandle, SamplingParams,
                              host_snapshot, run_requests)
 from repro.serve.paging import (BlockAllocator, DevicePageView, HostSwapPool,
@@ -147,6 +148,7 @@ class EngineStats:
 class ServeEngine:
     def __init__(self, model: Model, params: Any, *, slots: int = 4,
                  max_len: int = 256, tracer: Tracer | None = None):
+        refuse_paged_only(model.cfg, "ServeEngine")
         self.model = model
         self.params = params
         self.slots = slots
@@ -412,8 +414,11 @@ def _chunk_fn_for(model: Model, sampled: bool, attention: str | None,
             # (params, pool, tokens, pos, n_new, page_table[, lanes])
             ins = (None, pool, None, None, None, None) + (
                 (None,) if sampled else ())
+            # (tokens, logits, pool[, the step's counts])
+            outs = (None, None, pool) + (
+                (None,) if model.cfg.experts_held else ())
             fn = jax.jit(target, donate_argnums=(1,), in_shardings=ins,
-                         out_shardings=(None, None, pool))
+                         out_shardings=outs)
         steps[key] = fn
     return fn
 
@@ -529,6 +534,8 @@ class PagedServeEngine:
                 f"{model.cfg.family!r} serves through ServeEngine")
         if admit_every < 1:
             raise ValueError(f"admit_every must be >= 1, got {admit_every}")
+        if kernel == "gather":
+            refuse_paged_only(model.cfg, "the gather pathway")
         if kernel not in ("paged", "gather"):
             raise ValueError(
                 f"kernel must be 'paged' (attend through the page table) "
@@ -1053,12 +1060,13 @@ class PagedServeEngine:
             with tr.phase("serve.inputs"):
                 fn, args, n_new, counters = self._inputs()
             with tr.phase("serve.dispatch"):
-                sampled, logits, self.cache = fn(*args)
+                sampled, logits, self.cache, *rest = fn(*args)
+                counts = rest[0] if rest else {}
                 if self.kernel == "paged":
                     self.view.adopt(self.cache)
                 self.stats.decode_steps += 1
                 self.stats.observe_occupancy(len(self.active))
-                if counters is not None:
+                if counters is not None and not counts:
                     tr.emit("step", **counters)
             with tr.phase("serve.wait"):
                 nxt = np.asarray(sampled)
@@ -1066,6 +1074,10 @@ class PagedServeEngine:
                         if any(st.req.keep_logits
                                for st in self.active.values())
                         else None)
+                if counters is not None and counts:
+                    # the program's own counts, read to the host only
+                    # when traced, once the step has ended
+                    tr.emit("step", **counters, **self._step_counts(counts))
             with tr.phase("serve.commit"):
                 return self._commit(n_new, nxt, rows)
 
@@ -1156,8 +1168,36 @@ class PagedServeEngine:
                                 for st in self.active.values()),
                 pages_written=sum(pages_for(st.consumed, bs)
                                   for st in self.active.values()))
+            if self.model.cfg.sliding_window:
+                counters["window_pages"] = self._window_pages(
+                    counters["work"])
         fn = self._chunk_sample_fn if need_sample else self._chunk_fn
         return fn, args, n_new, counters
+
+    def _window_pages(self, work) -> int:
+        """Pages the kernel walks for the running lanes in the windowed
+        layers, summed over those layers: a lane's walk runs from its
+        window's first page to its last (``kernels.paged_attention``)."""
+        bs = self.alloc.block_size
+        n_pages = pages_for(self.max_len, bs)
+        window = self.model.cfg.sliding_window
+        per_layer = sum(
+            min((p + max(n, 1) - 1) // bs, n_pages - 1)
+            - max(p - window + 1, 0) // bs + 1 for p, n in work)
+        layers = sum(1 for w in self.model.cfg.layer_windows() if w)
+        return layers * per_layer
+
+    @staticmethod
+    def _step_counts(counts: dict) -> dict:
+        """The ``step`` event's fields from the paged step's own counts:
+        ``expert_rows`` (rows routed to held experts) and
+        ``expert_rows_max`` (the most one held expert took), each summed
+        over the expert layers."""
+        out = {}
+        if "experts" in counts:
+            rows, most = np.asarray(counts["experts"]).tolist()
+            out.update(expert_rows=rows, expert_rows_max=most)
+        return out
 
     def _commit(self, n_new: np.ndarray, nxt: np.ndarray,
                 rows: np.ndarray | None) -> list[Request]:

@@ -56,7 +56,7 @@ def _sds(shape, dtype, sharding):
 
 
 def _compile_kernel(one_chip, *, slots, chunk, kv, group, hd, bs, pages,
-                    num_blocks):
+                    num_blocks, window=False):
     bf16, i32 = jnp.bfloat16, jnp.int32
     args = (
         _sds((slots, chunk, kv, group, hd), bf16, one_chip),      # q
@@ -66,7 +66,21 @@ def _compile_kernel(one_chip, *, slots, chunk, kv, group, hd, bs, pages,
         _sds((slots,), i32, one_chip),                             # pos
         _sds((slots,), i32, one_chip),                             # n_new
     )
+    if window:
+        return jax.jit(lambda *a: paged_attention_pallas(*a[:-1], window=a[-1])
+                       ).lower(*args, _sds((), i32, one_chip)).compile()
     return jax.jit(paged_attention_pallas).lower(*args).compile()
+
+
+def test_windowed_paged_kernel_compiles_for_v5e(one_chip):
+    """The kernel with a window at trinity-mini.decode-long's geometry:
+    32 slots, 4 kv heads of 8 query heads (256 query rows a head, all
+    four heads in one grid step), head_dim 128, 512 pages a lane."""
+    assert heads_per_block(4, 32 * 8, 16, 128, 2, 2) == 4
+    compiled = _compile_kernel(one_chip, slots=32, chunk=32, kv=4, group=8,
+                               hd=128, bs=16, pages=512, num_blocks=12288,
+                               window=True)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("shape", [
