@@ -109,7 +109,7 @@ def _compile_seconds():
 
 def kernel_vs_reference(engine, seed: int) -> float:
     """The Pallas kernel against ``paged_attention_ref`` on the served
-    pool's last layer (selected through the kernel's layer index map),
+    pool's last layer (selected in the kernel's page copies),
     random queries at the model's width, ragged lanes.  Returns the
     largest error over valid rows, relative to max|V| on the pages read."""
     import jax
@@ -119,8 +119,11 @@ def kernel_vs_reference(engine, seed: int) -> float:
     from repro.kernels.ops import paged_attention
 
     view = engine.view
-    layers, nb, kv, bs, hd = view.k.shape
+    layers, nb, kv, bs, _ = view.k.shape
     cfg = engine.model.cfg
+    # the model's own query width: the kernel meets the pool's wider
+    # heads (pad lanes) itself, as on the served path
+    hd = cfg.resolved_head_dim
     g = cfg.n_heads // cfg.n_kv_heads
     rng = np.random.default_rng(seed)
     n_pages = view.max_pages

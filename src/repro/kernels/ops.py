@@ -10,11 +10,14 @@ TPU without it fails to lower.  Model code calls these via
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.hh_neuron import hh_step_pallas
-from repro.kernels.paged_attention import (paged_attention_pallas,
-                                           paged_attention_ref)
+from repro.kernels.paged_attention import (kernel_blocks,
+                                           paged_attention_pallas,
+                                           paged_attention_ref,
+                                           pool_head_dim, walk_blocks)
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 #: Run Pallas kernels in interpret mode (test-only: tests/conftest.py sets
@@ -60,6 +63,30 @@ def flash_attention(q, k, v, *, causal: bool = True,
 def ssd_scan(x, dt, a, b_in, c_in, chunk: int):
     return ssd_scan_pallas(x, dt, a, b_in, c_in, chunk,
                            interpret=_interpret())
+
+
+def paged_attention_blocks(work, windows, *, q_shape, q_dtype, pool_shape,
+                           pool_dtype, n_pages: int) -> int:
+    """Compute blocks the Pallas kernel walks for the lanes ``work``
+    (``(pos, n_new)`` each) in layers of ``windows`` (0: the whole
+    context), summed over lanes, layers and kv-head blocks, for queries
+    ``q_shape`` over a pool ``pool_shape`` with a page table
+    ``n_pages`` wide.  Host arithmetic only, with the kernel's own block
+    sizes and loop bounds."""
+    kvb, pages = kernel_blocks(q_shape, pool_shape,
+                               jnp.dtype(q_dtype).itemsize,
+                               jnp.dtype(pool_dtype).itemsize, n_pages)
+    bs = pool_shape[-2]
+    per_layer = {w: sum(int(walk_blocks(p, n, w or None, bs, pages, n_pages))
+                        for p, n in work) for w in set(windows)}
+    return q_shape[2] // kvb * sum(per_layer[w] for w in windows)
+
+
+def paged_pool_width(head_dim: int, impl: str) -> int:
+    """The head width of a paged pool that ``impl`` attends: the Pallas
+    kernel copies pages only at whole 128-lane tiles
+    (``paged_attention.pool_head_dim``); the reference reads any."""
+    return pool_head_dim(head_dim) if impl == "pallas" else head_dim
 
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, n_new, *,

@@ -383,6 +383,11 @@ def paged_chunk_decode_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     # pool out around the kernel call, twice per layer)
     heads = jnp.arange(kv)[None, None, :]
     page, off = page[:, :, None], off[:, :, None]
+    if k_pool.shape[-1] > hd:
+        # the pool's heads are wider (``kops.paged_pool_width``): the lanes
+        # past hd are written zero
+        lanes = ((0, 0),) * 3 + ((0, k_pool.shape[-1] - hd),)
+        k_new, v_new = jnp.pad(k_new, lanes), jnp.pad(v_new, lanes)
     # named, so that the KV write is told apart from the kernel in a
     # profile (its operations carry ``kv_append`` in their op_name)
     with jax.named_scope("kv_append"):

@@ -554,17 +554,22 @@ def decode_chunk(cfg: ModelConfig, params: dict, cache: dict,
 # ======================================================= paged chunked decode
 
 
-def paged_cache_specs(cfg: ModelConfig, num_blocks: int,
-                      block_size: int) -> dict[str, Any]:
+def paged_cache_specs(cfg: ModelConfig, num_blocks: int, block_size: int,
+                      impl: str = "pallas") -> dict[str, Any]:
     """Cache ParamSpec tree for the paged decode step: the KV lives in a
-    shared page pool ``(layers, num_blocks, kv, block_size, hd)`` instead
-    of dense per-slot rows — head-major within a page, the layout the
-    Pallas kernel's K/V blocks tile (``kernels.paged_attention``).
+    shared page pool ``(layers, num_blocks, kv, block_size, width)``
+    instead of dense per-slot rows — head-major within a page, the layout
+    the Pallas kernel copies page by page (``kernels.paged_attention``),
+    each head as wide as the attention ``impl`` that reads the pool needs
+    (``kernels.ops.paged_pool_width``; zero lanes past ``hd``).
     Dense/moe only (attention caches)."""
     if cfg.family not in ("dense", "moe"):
         raise ValueError(f"paged cache supports dense/moe, got {cfg.family}")
+    from repro.kernels import ops as kops
+
     geom = head_geom(cfg, tp_size())
-    shape = (cfg.n_layers, num_blocks, geom.n_kv, block_size, geom.head_dim)
+    shape = (cfg.n_layers, num_blocks, geom.n_kv, block_size,
+             kops.paged_pool_width(geom.head_dim, impl))
     axes = ("layers", None, "cache_kv", None, None)
     return {"paged": {
         "k": P.ParamSpec(shape, axes, init="zeros"),

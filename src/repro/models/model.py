@@ -146,8 +146,9 @@ class Model:
     def cache_specs(self, batch: int, seq_len: int):
         return D.cache_specs(self.cfg, batch, seq_len)
 
-    def paged_cache_specs(self, num_blocks: int, block_size: int):
-        return D.paged_cache_specs(self.cfg, num_blocks, block_size)
+    def paged_cache_specs(self, num_blocks: int, block_size: int,
+                          impl: str = "pallas"):
+        return D.paged_cache_specs(self.cfg, num_blocks, block_size, impl)
 
     def abstract_cache(self, batch: int, seq_len: int):
         return P.abstract(self.cache_specs(batch, seq_len))

@@ -573,14 +573,15 @@ class PagedServeEngine:
             # per-slot working cache, no admission gather.  Geometry
             # comes from the declarative spec the jitted paged step is
             # written against, so the two cannot drift.
-            spec = model.paged_cache_specs(num_blocks, block_size)
-            layers, _, n_kv, _, hd = spec["paged"]["k"].shape
+            spec = model.paged_cache_specs(num_blocks, block_size,
+                                           self.attention)
+            layers, _, n_kv, _, width = spec["paged"]["k"].shape
             self.pool = None
             self.view = DevicePageView(
-                num_blocks, block_size, layers, n_kv, hd,
-                spec["paged"]["k"].dtype,
+                num_blocks, block_size, layers, n_kv,
+                model.cfg.resolved_head_dim, spec["paged"]["k"].dtype,
                 slots=slots, max_pages=pages_for(max_len, block_size),
-                device=_device_of(params))
+                width=width, device=_device_of(params))
             self.cache = self.view.cache()
         else:
             # dense-cache geometry without materializing it twice
@@ -1171,6 +1172,8 @@ class PagedServeEngine:
             if self.model.cfg.sliding_window:
                 counters["window_pages"] = self._window_pages(
                     counters["work"])
+            if self.kernel == "paged":
+                counters["attn_blocks"] = self._attn_blocks(counters["work"])
         fn = self._chunk_sample_fn if need_sample else self._chunk_fn
         return fn, args, n_new, counters
 
@@ -1186,6 +1189,20 @@ class PagedServeEngine:
             - max(p - window + 1, 0) // bs + 1 for p, n in work)
         layers = sum(1 for w in self.model.cfg.layer_windows() if w)
         return layers * per_layer
+
+    def _attn_blocks(self, work) -> int:
+        """Compute blocks the paged kernel walks for the running lanes,
+        summed over the layers: each lane's walk in blocks of the
+        kernel's pages, a windowed layer's from its window's first page
+        (``kernels.paged_attention.walk_blocks``)."""
+        cfg, pool = self.model.cfg, self.view.k
+        kv = pool.shape[2]
+        return kops.paged_attention_blocks(
+            work, cfg.layer_windows(),
+            q_shape=(self.slots, self.chunk, kv, cfg.n_heads // kv,
+                     cfg.resolved_head_dim),
+            q_dtype=cfg.dtype, pool_shape=pool.shape, pool_dtype=pool.dtype,
+            n_pages=self.view.max_pages)
 
     @staticmethod
     def _step_counts(counts: dict) -> dict:

@@ -474,19 +474,25 @@ def pool_format(device) -> "Format":
 
 
 @functools.lru_cache(maxsize=None)
-def _page_movers(fmt):
+def _page_movers(fmt, head_dim: int):
     """Fixed-shape jitted page movers for one pool layout.  The page index
     is a *traced* argument, so each compiles once per pool shape (eager
     ``.at[idx].set`` would bake the index in and recompile on nearly
-    every swap); the write donates the pool and updates it in place."""
+    every swap); the write donates the pool and updates it in place.
+    Pages leave the pool at ``head_dim`` and come back padded with zero
+    lanes to its width."""
     import jax
+    import jax.numpy as jnp
 
     def read(pool, bid):
-        return jax.lax.dynamic_slice_in_dim(pool, bid, 1, axis=1)[:, 0]
+        return jax.lax.dynamic_slice_in_dim(pool, bid, 1,
+                                            axis=1)[:, 0, ..., :head_dim]
 
     def write(pool, bid, page):
-        return jax.lax.dynamic_update_slice_in_dim(pool, page[:, None], bid,
-                                                   axis=1)
+        lanes = ((0, 0),) * (page.ndim - 1) + (
+            (0, pool.shape[-1] - head_dim),)
+        return jax.lax.dynamic_update_slice_in_dim(
+            pool, jnp.pad(page, lanes)[:, None], bid, axis=1)
 
     return (jax.jit(read, in_shardings=(fmt, None)),
             jax.jit(write, in_shardings=(fmt, None, None), out_shardings=fmt,
@@ -498,16 +504,18 @@ class DevicePageView:
     paged-attention kernel (``kernels.paged_attention``).
 
     The pool arrays ``k``/``v`` — ``(layers, num_blocks, kv, block_size,
-    hd)``, head-major within a page as the Pallas kernel's K/V blocks
-    need, in the pinned row-major layout of :func:`pool_format` — ARE the
+    width)``, head-major within a page as the Pallas kernel's page copies
+    need, each head ``head_dim`` wide with zero lanes up to the pool's
+    ``width`` (``models.decode.paged_cache_specs`` sizes it), in the
+    pinned row-major layout of :func:`pool_format` — ARE the
     KV storage on the kernel path: the jitted paged step writes fresh
     rows into them through the page table and attends every page through
     the same table, so prefix sharing is pure metadata (a shared page
     appears in many slots' table rows) and registration copies nothing.
     The arrays are donated to the step and re-adopted from its output
     each tick (``cache()`` / ``adopt()``); the swap tier moves single
-    pages ``(layers, kv, block_size, hd)`` with ``read_page`` /
-    ``write_page``.
+    pages ``(layers, kv, block_size, head_dim)`` with ``read_page`` /
+    ``write_page``: the host tier holds no pad lanes.
 
     ``page_table`` is the host mirror the engine keeps in sync with the
     ``BlockAllocator``: ``bind_slot`` installs a slot's ordered physical
@@ -520,25 +528,28 @@ class DevicePageView:
 
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  n_kv: int, head_dim: int, dtype, *, slots: int,
-                 max_pages: int, device=None):
+                 max_pages: int, width: int | None = None, device=None):
         import jax
         import jax.numpy as jnp
 
-        shape = (layers, num_blocks, n_kv, block_size, head_dim)
+        shape = (layers, num_blocks, n_kv, block_size, width or head_dim)
         self.format = pool_format(device or jax.devices()[0])
         zeros = jax.jit(lambda: jnp.zeros(shape, dtype),
                         out_shardings=self.format)
         self.k = zeros()
         self.v = zeros()
-        self._read, self._write = _page_movers(self.format)
+        self._read, self._write = _page_movers(self.format, head_dim)
+        self.head_dim = head_dim
         self.block_size = block_size
         self.max_pages = max_pages
         self.page_table = np.zeros((slots, max_pages), np.int32)
 
     @property
     def nbytes(self) -> int:
-        """Logical bytes of both pools (before any on-chip lane padding)."""
-        return self.k.nbytes + self.v.nbytes
+        """Logical bytes of both pools at ``head_dim`` (before the pad
+        lanes up to the pool's width, and any on-chip lane padding)."""
+        per_lane = (self.k.nbytes + self.v.nbytes) // self.k.shape[-1]
+        return per_lane * self.head_dim
 
     # ------------------------------------------------------------- tables
     def bind_slot(self, slot: int, blocks: Sequence[int]) -> None:

@@ -23,11 +23,12 @@ from bench.tests.afmoe_tiny import CONFIG  # noqa: E402
 from repro.audit.trace import Tracer  # noqa: E402
 from repro.configs.archs import TRINITY_MINI  # noqa: E402
 from repro.configs.base import reduced  # noqa: E402
-from repro.kernels.paged_attention import (first_page,  # noqa: E402
-                                           kv_page_index_windowed,
+from repro.kernels.paged_attention import (block_pages,  # noqa: E402
+                                           first_page, kernel_blocks,
                                            last_page,
                                            paged_attention_pallas,
-                                           paged_attention_ref)
+                                           paged_attention_ref,
+                                           walk_blocks)
 from repro.models import moe  # noqa: E402
 from repro.serve import PagedServeEngine, Request  # noqa: E402
 
@@ -217,26 +218,21 @@ def test_windowed_kernel_matches_the_reference(window):
 
 
 def test_windowed_walk_names_no_page_before_the_window():
-    """The windowed K/V index map names each lane's pages from its
-    window's first to its last, and that first page for every earlier
-    grid step: nothing before the window is fetched."""
-    bs, n_pages, window = 16, 12, 40
-    pt = jnp.arange(3 * n_pages, dtype=jnp.int32).reshape(3, n_pages) + 50
-    pos = jnp.array([100, 30, 150], jnp.int32)
-    nn = jnp.array([1, 32, 8], jnp.int32)
-    layer, win = jnp.array([2], jnp.int32), jnp.array([window], jnp.int32)
+    """A windowed layer's walk starts at the window's first page: its
+    blocks copy each lane's pages from that page to its last, in order,
+    and none before it."""
+    bs, n_pages, window, pages = 16, 12, 40, 3
+    pos = np.array([100, 30, 150])
+    nn = np.array([1, 32, 8])
     firsts = first_page(pos, window, bs)
     lasts = last_page(pos, nn, bs, n_pages)
     assert firsts.tolist() == [3, 0, 6]
     for b in range(3):
         first, last = int(firsts[b]), int(lasts[b])
-        at = [kv_page_index_windowed(b, 0, j, layer, win, pt, pos, nn,
-                                     block_size=bs, n_pages=n_pages)
-              for j in range(n_pages)]
-        named = {int(a[1]) for a in at}
-        assert named == {int(pt[b, j]) for j in range(first, last + 1)}
-        for j in range(first):
-            assert int(at[j][1]) == int(pt[b, first])
+        n = int(walk_blocks(pos[b], nn[b], window, bs, pages, n_pages))
+        copied = [int(p) for i in range(n)
+                  for p in block_pages(first, i, pages) if p <= last]
+        assert copied == list(range(first, last + 1))
 
 
 # -------------------------------------------------------- engine and paths
@@ -244,7 +240,9 @@ def test_windowed_walk_names_no_page_before_the_window():
 
 def test_step_events_count_the_window_and_the_experts(tiny):
     """A traced engine's ``step`` events carry the windowed walk's pages
-    (from ``work`` and the window) and the rows the held experts took."""
+    (from ``work`` and the window), the kernel's compute blocks over
+    every layer (each windowed layer's from the window's first page) and
+    the rows the held experts took."""
     model, params = tiny
     tr = Tracer()
     rng = np.random.default_rng(4)
@@ -253,10 +251,17 @@ def test_step_events_count_the_window_and_the_experts(tiny):
     events = [e.data for e in tr.events("step")]
     assert events
     windowed = sum(1 for w in model.cfg.layer_windows() if w)
+    kv, hd = model.cfg.n_kv_heads, model.cfg.resolved_head_dim
+    # the CPU engine's pool is as wide as the reference reads it: hd
+    _, pages = kernel_blocks((3, 8, kv, model.cfg.n_heads // kv, hd),
+                             (1, 1, kv, 8, hd), 2, 2, 12)
     for ev in events:
         walk = sum(int(last_page(p, n, 8, 12)) - int(first_page(p, 16, 8))
                    + 1 for p, n in ev["work"])
         assert ev["window_pages"] == windowed * walk
+        assert ev["attn_blocks"] == sum(
+            int(walk_blocks(p, n, w or None, 8, pages, 12))
+            for w in model.cfg.layer_windows() for p, n in ev["work"])
         fed = sum(n for _, n in ev["work"])
         # every expert layer sends each fed row to 0..8 held experts
         assert 0 <= ev["expert_rows"] <= 6 * fed * 4

@@ -21,10 +21,14 @@ except ImportError:  # invariants still run via the conftest property loop
     from conftest import given, settings, st
 
 from repro.configs import ALL_ARCHS
-from repro.kernels.paged_attention import (VMEM_BUDGET, heads_per_block,
-                                           kv_page_index, last_page,
-                                           paged_attention_pallas,
-                                           paged_attention_ref)
+from repro.kernels import paged_attention as pa
+from repro.kernels.paged_attention import (MAX_BLOCK_KEYS, VMEM_BUDGET,
+                                           block_pages, block_vmem,
+                                           first_page, heads_per_block,
+                                           last_page, paged_attention_pallas,
+                                           paged_attention_ref,
+                                           pages_per_block, pool_head_dim,
+                                           walk_blocks)
 
 pytestmark = pytest.mark.kernel
 
@@ -93,97 +97,162 @@ def test_kernel_dtype_sweep(dtype, tol):
     _assert_parity(args, rtol=tol, atol=tol)
 
 
-def _served_case(kv, g, hd, c, *, bs=16, n_pages=6, seed=0):
-    """Three lanes as the engine leaves them, bf16 as the pool holds them:
-    an idle slot (cleared table row, pos 0, n_new 0), a lane whose last
-    row sits mid-page, and one whose last page is exactly full.  Each
-    running lane's row holds its written pages, then distinct pages
-    reserved at admission for its answer — never written, so NaN here —
-    then 0, a finite page.  Returns the arguments, and the same with the
-    reserved pages finite for the reference: its softmax gives those rows
-    weight 0, and 0 × NaN would poison its valid rows."""
+def _served_case(kv, g, hd, c, *, bs=16, n_pages=6, lanes=None,
+                 window=None, layers=1, layer=0, pool_hd=None, seed=0):
+    """Lanes as the engine leaves them, bf16 as the pool holds them.
+    ``lanes`` lists each slot's ``(rows after this call, rows fed)``, or
+    None for an idle slot (cleared table row, pos 0, n_new 0); the
+    default is an idle slot, a lane whose last row sits mid-page and one
+    whose last page is exactly full.  A running lane's row holds its
+    written pages, then pages reserved at admission for its answer
+    (never written) up to the table's end.  Every page its walk must
+    not read holds NaN here: the reserved ones, those before a window's
+    first page, and every page of the pool's other layers; physical page
+    0 (an idle slot's) is finite.  ``pool_hd`` pads the pool's heads with
+    zero lanes.  Returns the arguments, and the same with those pages
+    finite for the reference: its softmax gives them weight 0, and 0 ×
+    NaN would poison its valid rows."""
     rng = np.random.default_rng(seed)
-    ends = [2 * bs + 5, 3 * bs]                  # rows after this call
-    n_new = [0, c, c]
-    pos = [0] + [t - c for t in ends]
-    written = [t // bs + (t % bs > 0) for t in ends]
-    reserved = 2
-    nb = 1 + sum(written) + reserved * len(ends)
-    kp = rng.standard_normal((nb, kv, bs, hd)).astype(np.float32)
-    vp = rng.standard_normal((nb, kv, bs, hd)).astype(np.float32)
+    if lanes is None:
+        lanes = [None, (2 * bs + 5, c), (3 * bs, c)]
+    pool_hd = pool_hd or hd
+    running = [ln for ln in lanes if ln is not None]
+    nb = 1 + len(running) * n_pages
+    shape = (layers, nb, kv, bs, pool_hd)
+    kp = np.zeros(shape, np.float32)
+    vp = np.zeros(shape, np.float32)
+    kp[..., :hd] = rng.standard_normal(shape[:-1] + (hd,))
+    vp[..., :hd] = rng.standard_normal(shape[:-1] + (hd,))
     ids = iter(rng.permutation(np.arange(1, nb)))
-    pt = np.zeros((3, n_pages), np.int32)
+    pt = np.zeros((len(lanes), n_pages), np.int32)
+    pos, n_new = np.zeros(len(lanes), np.int32), np.zeros(len(lanes),
+                                                          np.int32)
+    unread = [p for p in range(nb) if p]
+    for slot, lane in enumerate(lanes):
+        if lane is None:
+            continue
+        end, fed = lane
+        pos[slot], n_new[slot] = end - fed, fed
+        pt[slot] = [next(ids) for _ in range(n_pages)]
+        first = int(first_page(end - fed, window, bs)) if window else 0
+        last = int(last_page(end - fed, fed, bs, n_pages))
+        for j in range(first, last + 1):
+            unread.remove(pt[slot, j])
     kn, vn = kp.copy(), vp.copy()
-    for lane, w in zip((1, 2), written):
-        pt[lane, :w + reserved] = [next(ids) for _ in range(w + reserved)]
-        kn[pt[lane, w:w + reserved]] = np.nan
-        vn[pt[lane, w:w + reserved]] = np.nan
-    q = rng.standard_normal((3, c, kv, g, hd))
+    kn[:, unread] = vn[:, unread] = np.nan
+    others = [li for li in range(layers) if li != layer]
+    kn[others] = vn[others] = np.nan
+    q = rng.standard_normal((len(lanes), c, kv, g, hd))
     bf = lambda a: jnp.asarray(a, jnp.bfloat16)
-    lanes = (jnp.asarray(pt), jnp.asarray(pos, jnp.int32),
-             jnp.asarray(n_new, jnp.int32))
-    return ((bf(q), bf(kn), bf(vn)) + lanes,
-            (bf(q), bf(kp), bf(vp)) + lanes)
+    state = (jnp.asarray(pt), jnp.asarray(pos), jnp.asarray(n_new))
+    kw = dict(window=window, layer=layer)
+    return ((bf(q), bf(kn), bf(vn)) + state, kw,
+            (bf(q), bf(kp[layer]), bf(vp[layer])) + state)
 
 
-def _assert_served_parity(served, finite):
-    """The kernel on the pool with NaN reserved pages: every output row
-    finite, and each lane's valid rows as the reference gives them on
-    the same pool with those pages finite."""
-    out = np.asarray(paged_attention_pallas(*served, interpret=True),
+def _assert_served_parity(served, kw, finite):
+    """The kernel on the pool with NaN pages outside every walk: every
+    output row finite, and each lane's valid rows as the reference gives
+    them on the same layer with those pages finite."""
+    out = np.asarray(paged_attention_pallas(*served, interpret=True, **kw),
                      np.float32)
     assert np.isfinite(out).all()
-    ref = np.asarray(paged_attention_ref(*finite), np.float32)
+    ref = np.asarray(paged_attention_ref(*finite, window=kw["window"]),
+                     np.float32)
     n_new = np.asarray(served[5])
     for b, n in enumerate(n_new.tolist()):
         np.testing.assert_allclose(out[b, :n], ref[b, :n], rtol=1e-2,
                                    atol=1e-2, err_msg=f"lane {b}")
 
 
-@pytest.mark.parametrize("c", [1, 32], ids=["decode", "chunk32"])
-@pytest.mark.parametrize("hd", [96, 128])
-@pytest.mark.parametrize("kv,g", [(4, 1), (8, 1), (2, 4), (4, 2)],
-                         ids=["mha-kv4", "mha-kv8", "gqa-kv2-g4",
-                              "gqa-kv4-g2"])
-def test_kernel_matches_reference_on_served_lanes(kv, g, hd, c):
-    """All kv heads of a page in one grid step: valid rows match the
-    reference, and the NaN pages reserved past a lane's last page reach
-    no output row (the clamp of the walk itself is pinned by
+_HEADS = [((4, 1), "mha-kv4"), ((8, 1), "mha-kv8"), ((2, 4), "gqa-kv2-g4"),
+          ((4, 2), "gqa-kv4-g2")]
+_SERVED = [pytest.param(kv, g, hd, c, 0, {}, id=f"{name}-{hd}-{cid}")
+           for (kv, g), name in _HEADS for hd in (96, 128)
+           for c, cid in ((1, "decode"), (32, "chunk32"))] + [
+    # three blocks of two pages, the last one partial
+    pytest.param(2, 4, 128, 8, 32, dict(n_pages=6, lanes=[(4 * 16 + 3, 8)]),
+                 id="walk-of-several-blocks-ending-partial"),
+    # the window's first page (4) falls inside a block of three counted
+    # from page 0, and mid-page
+    pytest.param(2, 4, 128, 8, 48, dict(
+        n_pages=9, window=40, lanes=[(7 * 16 + 5, 8), (3 * 16 + 1, 1)]),
+        id="window-first-page-mid-block"),
+    # lanes of very different lengths, idle slots between them: each grid
+    # step walks its own lane, whatever the last one left in the buffers
+    pytest.param(4, 2, 96, 4, 32, dict(
+        n_pages=12, pool_hd=128, lanes=[(1, 1), None, (11 * 16 + 9, 4),
+                                        (2 * 16, 1), None, (6 * 16 + 2, 2)]),
+        id="lanes-of-mixed-lengths-and-idle"),
+    # 7 table pages in blocks of 3: the last block of a full-table walk
+    # holds one page
+    pytest.param(2, 2, 128, 4, 48, dict(n_pages=7, lanes=[(7 * 16, 4),
+                                                          (5 * 16 + 1, 1)]),
+                 id="table-not-a-multiple-of-the-block"),
+    # a stacked pool read at layer 2 of 3, the others NaN
+    pytest.param(2, 2, 128, 4, 32, dict(
+        n_pages=6, layers=3, layer=2, window=24,
+        lanes=[(5 * 16 + 4, 4), None, (2 * 16 + 3, 1)]),
+        id="stacked-pool-layer-2"),
+]
+
+
+@pytest.mark.parametrize("kv,g,hd,c,max_keys,walk", _SERVED)
+def test_kernel_matches_reference_on_served_lanes(kv, g, hd, c, max_keys,
+                                                  walk, monkeypatch):
+    """All kv heads of a lane in one grid step: valid rows match the
+    reference, and no page outside a lane's walk — reserved past its
+    last page, before its window, in another layer: all NaN here —
+    reaches an output row.  ``max_keys`` cuts the compute block to that
+    many keys (0: the kernel's own) so small tables walk several blocks
+    (the walk's pages themselves are pinned by
     ``test_walk_is_clamped_to_each_lanes_last_page``)."""
+    if max_keys:
+        monkeypatch.setattr(pa, "MAX_BLOCK_KEYS", max_keys)
     _assert_served_parity(*_served_case(
-        kv, g, hd, c, seed=kv * 1000 + g * 100 + hd + c))
+        kv, g, hd, c, seed=kv * 1000 + g * 100 + hd + c + max_keys, **walk))
 
 
 def test_kv_heads_split_across_grid_steps_when_they_do_not_fit(monkeypatch):
     """A budget that holds two of four kv heads splits the heads over the
     grid's middle axis; the result is the same."""
-    from repro.kernels import paged_attention as pa
-
     monkeypatch.setattr(pa, "VMEM_BUDGET", 400 * 2**10)
     assert heads_per_block(4, 32, 16, 96, 2, 2) == 2
     _assert_served_parity(*_served_case(4, 1, 96, 32, seed=77))
 
 
 def test_walk_is_clamped_to_each_lanes_last_page():
-    """The K/V index map names a lane's pages in order up to its last
-    one, and that last page for every later step of the walk, so the
-    pipeline fetches nothing past it (rows past the table's end clip to
-    its last page)."""
-    bs, n_pages = 16, 8
-    pt = jnp.arange(3 * n_pages, dtype=jnp.int32).reshape(3, n_pages) + 100
-    pos = jnp.array([0, 20, 100], jnp.int32)
-    nn = jnp.array([0, 1, 32], jnp.int32)
-    layer = jnp.array([3], jnp.int32)
-    lasts = last_page(pos, nn, bs, n_pages)
-    assert lasts.tolist() == [0, 1, n_pages - 1]
-    for b, last in enumerate(lasts.tolist()):
-        at = [tuple(int(i) for i in kv_page_index(
-            b, 0, j, layer, pt, pos, nn, block_size=bs, n_pages=n_pages))
-            for j in range(n_pages)]
-        for j in range(n_pages):
-            assert at[j] == (3, int(pt[b, min(j, last)]), 0, 0, 0)
-            if j > last:
-                assert at[j] == at[last]
+    """Block i of a lane's walk names pages ``first + i·P`` on, in order,
+    and copies those up to the lane's last page: over the walk every page
+    from the first (the window's, else 0) to the last is copied once and
+    no other (rows past the table's end clip to its last page).
+    ``walk_blocks`` is the count of blocks the kernel's loop visits,
+    counted here one by one."""
+    bs = 16
+    lanes = [(0, 0), (20, 1), (100, 32), (127, 1), (300, 8)]
+    for n_pages, pages, window in [(8, 1, None), (8, 3, None), (8, 8, None),
+                                   (20, 3, 40), (20, 4, 64), (7, 3, None),
+                                   (20, 16, 1000)]:
+        for pos, nn in lanes:
+            last = int(last_page(pos, nn, bs, n_pages))
+            first = int(first_page(pos, window, bs)) if window else 0
+            first = min(first, last)
+            n_blocks = 0
+            while first + n_blocks * pages <= last:     # the loop's visits
+                n_blocks += 1
+            case = (n_pages, pages, window, pos, nn)
+            assert int(walk_blocks(pos, nn, window, bs, pages,
+                                   n_pages)) == n_blocks, case
+            copied = [[p for p in block_pages(first, i, pages) if p <= last]
+                      for i in range(n_blocks)]
+            for i, block in enumerate(copied):
+                want = [first + i * pages + k for k in range(pages)
+                        if first + i * pages + k <= last]
+                assert block == want and block, case
+            flat = [p for block in copied for p in block]
+            assert flat == list(range(first, last + 1)), case
+    assert last_page(jnp.array([0, 20, 100]), jnp.array([0, 1, 32]), bs,
+                     8).tolist() == [0, 1, 7]
 
 
 def test_every_config_folds_all_kv_heads_into_one_grid_step():
@@ -200,6 +269,27 @@ def test_every_config_folds_all_kv_heads_into_one_grid_step():
     assert heads_per_block(48, 512, 16, 256, 2, 2) == 2
     assert heads_per_block(1, 4096, 16, 256, 4, 4) == 1   # never below one
     assert VMEM_BUDGET < 16 * 2**20
+
+
+def test_every_config_walks_several_pages_a_block():
+    """At chunk 32, 16-row pages and bf16, every configuration's kv-head
+    block gets a compute block of at least two pages, within the VMEM
+    budget and the key cap, with the pool's heads at whole tiles; one
+    page more would pass one of them.  Where nothing fits, one page."""
+    for cfg in ALL_ARCHS.values():
+        if not cfg.n_kv_heads:
+            continue
+        kv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+        hd = pool_head_dim(cfg.resolved_head_dim)
+        assert hd % 128 == 0 and hd - cfg.resolved_head_dim < 128
+        kvb = heads_per_block(kv, 32 * g, 16, hd, 2, 2)
+        p = pages_per_block(kvb, 32 * g, 16, hd, 2, 2, 256)
+        assert p >= 2, cfg.name
+        assert block_vmem(kvb, 32 * g, 16, hd, 2, 2, p) <= VMEM_BUDGET
+        assert (p + 1) * 16 > MAX_BLOCK_KEYS or block_vmem(
+            kvb, 32 * g, 16, hd, 2, 2, p + 1) > VMEM_BUDGET, cfg.name
+    assert pages_per_block(4, 256, 16, 128, 2, 2, 3) == 3   # the table's
+    assert pages_per_block(1, 4096, 16, 256, 4, 4, 256) == 1
 
 
 # ------------------------------------------------------------------- edges

@@ -12,7 +12,8 @@ from jax.profiler import ProfileData
 
 from repro.audit.trace import KNOWN_KINDS, NULL_TRACER, Tracer
 from repro.configs import ALL_ARCHS, reduced
-from repro.kernels.paged_attention import last_page
+from repro.kernels import paged_attention as pa
+from repro.kernels.paged_attention import last_page, walk_blocks
 from repro.models import build
 from repro.serve import PagedServeEngine, Request
 from repro.serve.paging import pages_for
@@ -156,3 +157,26 @@ def test_pages_attended_is_each_slots_walk_to_its_last_page(served):
     assert any(e.data["decode_lanes"] for e in steps)
     assert any(e.data["lanes"] < SLOTS for e in steps)
     assert tr.count("finish") == 3
+
+
+def test_attn_blocks_is_each_lanes_walk_in_the_kernels_blocks(served,
+                                                             monkeypatch):
+    """``attn_blocks`` on each ``step`` event is the compute blocks the
+    kernel's loop walks: :func:`walk_blocks` of each running lane at the
+    kernel's pages a block, summed over the layers.  Blocks of two pages
+    here, so a lane past two pages walks more than one."""
+    cfg, model, params = served
+    monkeypatch.setattr(pa, "MAX_BLOCK_KEYS", 2 * BS)
+    tr = Tracer()
+    eng = _engine(model, params, tr)
+    max_pages = pages_for(64, BS)
+    _run(eng, _requests(cfg))
+    steps = [e.data for e in tr.events("step")]
+    assert steps
+    for ev in steps:
+        walk = sum(int(walk_blocks(pos, n, None, BS, 2, max_pages))
+                   for pos, n in ev["work"])
+        assert ev["attn_blocks"] == cfg.n_layers * walk
+    # some lane walked more than one block
+    assert any(ev["attn_blocks"] > cfg.n_layers * ev["lanes"]
+               for ev in steps)

@@ -11,7 +11,8 @@ from repro.models import build
 from repro.serve.api import SamplingParams
 from repro.serve.engine import PagedServeEngine, Request
 from repro.serve.paging import (BlockAllocator, BlockAllocatorError,
-                                HostSwapPool, PrefixCache, chain_hashes)
+                                DevicePageView, HostSwapPool, PrefixCache,
+                                chain_hashes)
 from repro.serve.scheduler import PREEMPTED, RUNNING, SwapCostModel
 
 
@@ -24,6 +25,27 @@ def served():
 
 
 # ================================================== host swap pool units
+
+
+def test_device_pages_reach_the_host_at_the_model_head_width():
+    """A pool wider than the model's heads (pad lanes, as the kernel's
+    page copies need) moves pages to and from the host tier at the
+    model's ``head_dim``: the host holds no pad lanes, a written page
+    reads back as it was, its pad lanes stay zero, and ``nbytes`` counts
+    the heads alone."""
+    view = DevicePageView(4, 4, 2, 1, 3, np.float32, slots=1, max_pages=2,
+                          width=8)
+    assert view.k.shape == (2, 4, 1, 4, 8)
+    k_rows, v_rows = _rows(5)
+    view.write_page(2, k_rows, v_rows)
+    k_back, v_back = view.read_page(2)
+    assert k_back.shape == k_rows.shape
+    np.testing.assert_array_equal(k_back, k_rows)
+    np.testing.assert_array_equal(v_back, v_rows)
+    assert not np.asarray(view.k)[..., 3:].any()
+    assert view.nbytes == 2 * 2 * 4 * 1 * 4 * 3 * 4
+
+
 
 
 def _rows(fill, shape=(2, 1, 4, 3)):

@@ -18,8 +18,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import ALL_ARCHS
-from repro.kernels.paged_attention import (heads_per_block,
-                                           paged_attention_pallas)
+from repro.kernels.paged_attention import (heads_per_block, kernel_blocks,
+                                           paged_attention_pallas,
+                                           pool_head_dim)
 
 pytestmark = pytest.mark.kernel
 
@@ -56,52 +57,74 @@ def _sds(shape, dtype, sharding):
 
 
 def _compile_kernel(one_chip, *, slots, chunk, kv, group, hd, bs, pages,
-                    num_blocks, window=False):
+                    num_blocks, layers=1, window=False):
+    """The kernel over a stacked pool of ``layers`` (heads at the pool's
+    width) at a traced layer, as the layer scan calls it."""
     bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = (layers, num_blocks, kv, bs, pool_head_dim(hd))
     args = (
         _sds((slots, chunk, kv, group, hd), bf16, one_chip),      # q
-        _sds((num_blocks, kv, bs, hd), bf16, one_chip),            # k pool
-        _sds((num_blocks, kv, bs, hd), bf16, one_chip),            # v pool
+        _sds(pool, bf16, one_chip),                                # k pool
+        _sds(pool, bf16, one_chip),                                # v pool
         _sds((slots, pages), i32, one_chip),                       # table
         _sds((slots,), i32, one_chip),                             # pos
         _sds((slots,), i32, one_chip),                             # n_new
+        _sds((), i32, one_chip),                                   # layer
     )
     if window:
-        return jax.jit(lambda *a: paged_attention_pallas(*a[:-1], window=a[-1])
-                       ).lower(*args, _sds((), i32, one_chip)).compile()
-    return jax.jit(paged_attention_pallas).lower(*args).compile()
+        return jax.jit(lambda *a: paged_attention_pallas(
+            *a[:6], layer=a[6], window=a[7])).lower(
+                *args, _sds((), i32, one_chip)).compile()
+    return jax.jit(lambda *a: paged_attention_pallas(*a[:6], layer=a[6])
+                   ).lower(*args).compile()
+
+
+# the two cells' geometries: phi3-mini.decode-batch (8 slots of 32 MHA
+# heads of 96, a 640-page pool of 32 layers, 256 pages a lane) and
+# trinity-mini.decode-long (32 slots, 4 kv heads of 8 query heads, 128
+# wide, a 12,288-page pool of 16 layers, 512 pages a lane)
+PHI3_CELL = dict(slots=8, chunk=32, kv=32, group=1, hd=96, bs=16,
+                 pages=256, num_blocks=640, layers=32)
+TRINITY_CELL = dict(slots=32, chunk=32, kv=4, group=8, hd=128, bs=16,
+                    pages=512, num_blocks=12288, layers=16)
 
 
 def test_windowed_paged_kernel_compiles_for_v5e(one_chip):
-    """The kernel with a window at trinity-mini.decode-long's geometry:
-    32 slots, 4 kv heads of 8 query heads (256 query rows a head, all
-    four heads in one grid step), head_dim 128, 512 pages a lane."""
+    """The kernel with a window at both cells' geometries: all kv heads
+    in one grid step (256 query rows a head on trinity), several pages
+    a compute block."""
     assert heads_per_block(4, 32 * 8, 16, 128, 2, 2) == 4
-    compiled = _compile_kernel(one_chip, slots=32, chunk=32, kv=4, group=8,
-                               hd=128, bs=16, pages=512, num_blocks=12288,
-                               window=True)
-    assert "tpu_custom_call" in compiled.as_text()
+    for cell, blocks in ((TRINITY_CELL, (4, 16)), (PHI3_CELL, (32, 8))):
+        q = (cell["slots"], cell["chunk"], cell["kv"], cell["group"],
+             cell["hd"])
+        pool = (cell["layers"], cell["num_blocks"], cell["kv"], cell["bs"],
+                pool_head_dim(cell["hd"]))
+        assert kernel_blocks(q, pool, 2, 2, cell["pages"]) == blocks
+        compiled = _compile_kernel(one_chip, window=True, **cell)
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("shape", [
     # phi3-mini-3.8b: 32 kv heads (no GQA), head_dim 96 — not a multiple
-    # of 128, so the block's minor dim is the whole axis
+    # of 128, so the pool's heads are 128 wide and the queries padded
     dict(kv=32, group=1, hd=96),
     # a GQA width: 8 kv heads of 4 query heads each, head_dim 128
     dict(kv=8, group=4, hd=128),
-    # the phi3-mini.decode-batch cell's own geometry (8 slots, 256 pages
-    # of a 640-page pool): the folded blocks' VMEM at the served size
-    dict(kv=32, group=1, hd=96, slots=8, pages=256, num_blocks=640),
+    # the phi3-mini.decode-batch cell's own geometry: the blocks' VMEM
+    # at the served size
+    PHI3_CELL,
     # phi3 served at chunk 512 (``launch.serve --chunk``): 32 heads' blocks
     # overrun the VMEM budget, so a grid step holds 2 of them
     dict(kv=32, group=1, hd=96, chunk=512),
+    # the trinity-mini.decode-long cell's own geometry, no window
+    TRINITY_CELL,
 ], ids=["phi3-kv32-hd96", "gqa-kv8-g4-hd128", "phi3-cell-8slots-256pages",
-        "phi3-chunk512-split-heads"])
+        "phi3-chunk512-split-heads", "trinity-cell-32slots-512pages"])
 def test_paged_kernel_compiles_for_v5e(one_chip, shape):
     geometry = dict(slots=4, chunk=32, bs=16, pages=20, num_blocks=64)
     geometry |= shape
     if geometry["chunk"] == 512:
-        assert heads_per_block(32, 512, 16, 96, 2, 2) == 2
+        assert heads_per_block(32, 512, 16, 128, 2, 2) == 2
     compiled = _compile_kernel(one_chip, **geometry)
     assert "tpu_custom_call" in compiled.as_text()
 
